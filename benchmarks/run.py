@@ -55,6 +55,10 @@ def main(argv=None) -> None:
                         "after the benchmarks run (the obs-smoke artifact)")
     args = p.parse_args(argv)
 
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
+
     from benchmarks import (
         abft,
         data_movement,

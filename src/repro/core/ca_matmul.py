@@ -35,7 +35,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.core.decomposition import sfc_grid_factorization
 from repro.core.perf_model import TPU_V5E, HardwareModel, roofline_best_time
@@ -145,12 +144,12 @@ def ca_matmul(
         def body2d(a_loc: jax.Array, b_loc: jax.Array) -> jax.Array:
             return lm(a_loc, b_loc)
 
-        return shard_map(
+        return jax.shard_map(
             body2d,
             mesh=mesh,
             in_specs=(a_spec, b_spec),
             out_specs=out_spec,
-            check_rep=False,
+            check_vma=False,
         )(a, b)
 
     if reduce == "psum":
@@ -169,12 +168,12 @@ def ca_matmul(
             c_copy, kl_axis, scatter_dimension=1, tiled=True
         )
 
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(a_spec, b_spec),
         out_specs=out_spec,
-        check_rep=False,
+        check_vma=False,
     )(a, b)
 
 
@@ -224,9 +223,7 @@ def summa_ca_matmul(
             src = (my_col - s) % n_steps
             b_rows = lax.dynamic_slice_in_dim(b_loc, src * k_chunk, k_chunk, axis=0)
             a_nxt = lax.ppermute(a_cur, tn_axis, perm)  # in flight during dot
-            acc = acc + jnp.dot(
-                a_cur, b_rows, preferred_element_type=jnp.float32
-            )
+            acc = acc + lm(a_cur, b_rows).astype(jnp.float32)
             return (a_nxt, acc), None
 
         acc0 = jnp.zeros((a_loc.shape[0], b_loc.shape[1]), jnp.float32)
@@ -235,10 +232,10 @@ def summa_ca_matmul(
             acc = lax.psum(acc, kl_axis)
         return acc.astype(a_loc.dtype)
 
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(a_spec, b_spec),
         out_specs=out_spec,
-        check_rep=False,
+        check_vma=False,
     )(a, b)

@@ -39,6 +39,8 @@ from repro.core.decomposition import (
 __all__ = [
     "HardwareModel",
     "TPU_V5E",
+    "DEVICE_PEAKS",
+    "device_peaks",
     "BRGemmCounts",
     "simulate_patch_traversal",
     "simulate_gemm",
@@ -134,6 +136,24 @@ TPU_V5E = HardwareModel(
     fast_bytes=int(128 * 2**20 * 0.75),
     ici_beta=1.0 / 50e9,
 )
+
+# Published per-chip peaks, keyed by `jax.Device.device_kind`.  Source:
+# Google Cloud TPU documentation, "TPU v5e" (197 TFLOP/s bf16, 819 GB/s
+# HBM bandwidth, 16 GB HBM).  A kind missing here has no peak: add it with
+# its source rather than measuring against another chip's numbers.
+DEVICE_PEAKS: Dict[str, HardwareModel] = {"TPU v5 lite": TPU_V5E}
+
+
+def device_peaks(device_kind: str) -> HardwareModel:
+    """The published peaks of ``device_kind``; raises `KeyError` for a kind
+    the table does not hold."""
+    try:
+        return DEVICE_PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no published peaks for device kind {device_kind!r}; known: "
+            f"{sorted(DEVICE_PEAKS)}"
+        ) from None
 
 
 def gemm_flops(M: int, N: int, K: int) -> float:

@@ -23,7 +23,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams as _CompilerParams
 
 __all__ = ["flash_attention_pallas", "flash_attention"]
 
@@ -153,7 +152,7 @@ def flash_attention_pallas(
             pltpu.VMEM((q_chunk, 1), jnp.float32),
         ],
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
         ),
     )(q, k, v)

@@ -63,6 +63,7 @@ from repro.core.namespaces import (
 )
 from repro.core.perf_model import TPU_V5E, choose_knobs_analytical
 from repro.kernels.sfc_gemm import (
+    VMEM_LIMIT_BYTES,
     activation_fn,
     add_reduce_pallas,
     sfc_gemm_batched,
@@ -100,10 +101,12 @@ __all__ = [
     "chunk_gemm_plan",
 ]
 
-# Mosaic VMEM is ~16 MiB/core on current TPUs; when the fused step's working
-# set (double-buffered A/B panels + f32 accumulator(s) + C/epilogue tiles)
-# exceeds this, `sfc_matmul` falls back to the replicated two-launch path.
-_FUSED_VMEM_BYTES = 16 * 2**20
+# dual-B namespaces: two weight panels stream per grid step
+_DUAL_B_NAMESPACES = (NS_GLU, NS_NT_DUAL, NS_TN_DUAL, NS_TN_UPDATE_DUAL)
+
+# Mosaic's minor (lane) tile: a block's last dimension must be a multiple of
+# it or span the whole array dimension.
+_LANE = 128
 
 
 def default_interpret() -> bool:
@@ -113,6 +116,15 @@ def default_interpret() -> bool:
 
 def _round_up(x: int, mult: int) -> int:
     return ((x + mult - 1) // mult) * mult
+
+
+def _pad_contract(k: int, split: int) -> int:
+    """Contraction length padded to ``split`` equal chunks.  A chunk at
+    least one lane wide is kept a lane multiple: the chunk is the minor
+    dimension of the streamed A panel, and Mosaic refuses other widths."""
+    if split > 1 and k >= split * _LANE:
+        return _round_up(k, split * _LANE)
+    return _round_up(k, split)
 
 
 def pick_blocks(m: int, n: int, k: int) -> Tuple[int, int, int]:
@@ -141,7 +153,12 @@ def _resolve_knobs(
 ) -> Tuple[int, int, int, int]:
     """Fill unspecified knobs: measured tune-cache winner first (paper §III-C
     method (1)), analytical model + MXU alignment rules as the fallback.
-    ``op`` selects the tune-cache namespace ("gemm" or the dual-B "glu")."""
+    ``op`` selects the tune-cache namespace ("gemm" or the dual-B "glu").
+
+    A ``k_block_factor`` the caller left open is then raised until one
+    fused grid step fits `VMEM_LIMIT_BYTES` (`_bump_kbf_to_fit`), so the
+    plan Mosaic compiles never exceeds its scoped limit."""
+    auto_kbf = k_block_factor is None
     if None in (bm, bn, k_layers, k_block_factor):
         cached = None
         try:
@@ -167,6 +184,12 @@ def _resolve_knobs(
         )
         k_layers = k_layers or c
         k_block_factor = k_block_factor or kbf
+    if auto_kbf:
+        # residual tile counted: the caller's epilogue is not known here
+        k_block_factor = _bump_kbf_to_fit(
+            bm, bn, k, k_layers, k_block_factor, dtype, dtype,
+            dual=op in _DUAL_B_NAMESPACES, has_residual=True,
+        )
     return bm, bn, k_layers, k_block_factor
 
 
@@ -268,23 +291,27 @@ def fused_path_fits_vmem(
     has_residual: bool = False,
     opt_tile_sets: int = 0,
 ) -> bool:
-    """Does one fused grid step's working set fit the VMEM budget?
+    """Does one fused grid step's working set fit `VMEM_LIMIT_BYTES`?
 
-    Double-buffered A + B (x2 for GLU) panels, one f32 accumulator per B,
-    the output tile and any resident epilogue operands.  ``opt_tile_sets``
-    counts grad-and-update flush sets: each adds 3 resident f32 input tiles
-    (master/mu/nu) and 4 output tiles (W_new + three f32 states) — this is
-    why the update flush owns its own ``op="tn_update"`` tune namespace."""
+    Counts what Mosaic allocates against its scoped limit: every blocked
+    operand double-buffered — the A and B (x2 for GLU) panels, the output
+    tile, any resident epilogue operands — plus one f32 accumulator scratch
+    per B.  Rows are padded to the 16-row bf16 sublane tile.
+    ``opt_tile_sets`` counts grad-and-update flush sets: each adds 3
+    resident f32 input tiles (master/mu/nu) and 4 output tiles (W_new +
+    three f32 states) — this is why the update flush owns its own
+    ``op="tn_update"`` tune namespace."""
     n_b = 2 if glu else 1
-    panels = (bm * k_chunk + n_b * k_chunk * bn) * dtype_bytes * 2
-    accs = bm * bn * 4 * n_b
-    tiles = bm * bn * out_bytes
+    rows = _round_up(bm, 16)
+    blocks = (rows * k_chunk + n_b * k_chunk * bn) * dtype_bytes
+    blocks += rows * bn * out_bytes
     if has_residual:
-        tiles += bm * bn * dtype_bytes
-    tiles += 2 * bn * dtype_bytes  # bias / gate-bias rows (negligible)
+        blocks += rows * bn * dtype_bytes
+    blocks += 2 * 16 * bn * dtype_bytes  # bias / gate-bias rows
     if opt_tile_sets:
-        tiles += opt_tile_sets * bm * bn * (3 * 4 + 3 * 4 + out_bytes)
-    return panels + accs + tiles <= _FUSED_VMEM_BYTES
+        blocks += opt_tile_sets * rows * bn * (3 * 4 + 3 * 4 + out_bytes)
+    accs = rows * bn * 4 * n_b
+    return 2 * blocks + accs <= VMEM_LIMIT_BYTES
 
 
 def ensure_fused_fits(
@@ -311,7 +338,7 @@ def ensure_fused_fits(
     bm, bn, k_layers, k_block_factor = _resolve_knobs(
         m, n, k, jnp.dtype(dtype), None, None, None, None, op
     )
-    kp = _round_up(k, k_layers * k_block_factor)
+    kp = _pad_contract(k, k_layers * k_block_factor)
     out_dtype = out_dtype or dtype
     if not fused_path_fits_vmem(
         bm,
@@ -325,7 +352,7 @@ def ensure_fused_fits(
         raise VmemBudgetError(
             f"fused {op} plan ({m}x{n}x{k}, bm={bm}, bn={bn}, "
             f"k_layers={k_layers}, kbf={k_block_factor}) exceeds the "
-            f"{_FUSED_VMEM_BYTES >> 20} MiB VMEM budget"
+            f"{VMEM_LIMIT_BYTES >> 20} MiB VMEM budget"
         )
 
 
@@ -429,7 +456,7 @@ def _matmul_impl(
 
     mp = _round_up(m, bm)
     np_ = _round_up(n, bn)
-    kp = _round_up(k, k_layers * k_block_factor)
+    kp = _pad_contract(k, k_layers * k_block_factor)
 
     if fuse is None:
         fuse = fused_path_fits_vmem(
@@ -618,16 +645,18 @@ def _bump_kbf_to_fit(
     out_dtype,
     *,
     dual: bool,
+    has_residual: bool = False,
     opt_tile_sets: int = 0,
 ) -> int:
-    """The backward kernels have no replicated fallback: if the working set
-    of one grid step overflows the VMEM budget, chunk the contraction
-    harder (mirrors the grouped forward path's auto-resolution)."""
+    """Chunk the contraction harder until one grid step's working set fits
+    `VMEM_LIMIT_BYTES`: the smallest power-of-two multiple of ``kbf`` that
+    fits, chunks padded as the launch pads them (`_pad_contract`)."""
     dtype_bytes = jnp.dtype(dtype).itemsize
     out_bytes = jnp.dtype(out_dtype).itemsize
     while kbf < max(contract, 1) and not fused_path_fits_vmem(
-        bm, bn, _round_up(contract, k_layers * kbf) // (k_layers * kbf),
-        dtype_bytes, out_bytes, glu=dual, opt_tile_sets=opt_tile_sets,
+        bm, bn, _pad_contract(contract, k_layers * kbf) // (k_layers * kbf),
+        dtype_bytes, out_bytes, glu=dual, has_residual=has_residual,
+        opt_tile_sets=opt_tile_sets,
     ):
         kbf *= 2
     return kbf
@@ -668,19 +697,14 @@ def sfc_matmul_nt(
     dual = a2 is not None
     out_dtype = out_dtype or a.dtype
 
-    auto_kbf = k_block_factor is None
     bm, bn, k_layers, k_block_factor = _resolve_knobs(
         m, n, k, a.dtype, bm, bn, k_layers, k_block_factor,
         NS_NT_DUAL if dual else NS_NT,
     )
-    if auto_kbf:
-        k_block_factor = _bump_kbf_to_fit(
-            bm, bn, k, k_layers, k_block_factor, a.dtype, out_dtype, dual=dual
-        )
 
     mp = _round_up(m, bm)
     np_ = _round_up(n, bn)
-    kp = _round_up(k, k_layers * k_block_factor)
+    kp = _pad_contract(k, k_layers * k_block_factor)
 
     def pad2(x, rows, cols):
         r, c = x.shape
@@ -746,20 +770,15 @@ def sfc_matmul_tn(
     dual = b2 is not None
     out_dtype = out_dtype or a.dtype
 
-    auto_kbf = k_block_factor is None
     # the output is (K, N); the contraction runs over M
     bm, bn, k_layers, k_block_factor = _resolve_knobs(
         k, n, m, a.dtype, bm, bn, k_layers, k_block_factor,
         NS_TN_DUAL if dual else NS_TN,
     )
-    if auto_kbf:
-        k_block_factor = _bump_kbf_to_fit(
-            bm, bn, m, k_layers, k_block_factor, a.dtype, out_dtype, dual=dual
-        )
 
     kp = _round_up(k, bm)
     np_ = _round_up(n, bn)
-    mp = _round_up(m, k_layers * k_block_factor)
+    mp = _pad_contract(m, k_layers * k_block_factor)
 
     def pad2(x, rows, cols):
         r, c = x.shape
@@ -917,7 +936,7 @@ def sfc_matmul_tn_update(
 
     kp = _round_up(k, bm)
     np_ = _round_up(n, bn)
-    mp = _round_up(m, k_layers * k_block_factor)
+    mp = _pad_contract(m, k_layers * k_block_factor)
 
     def pad2(x, rows, cols):
         if x is None:
@@ -1156,7 +1175,7 @@ def sfc_grouped_matmul_nt(
             bm, bn, k, 1, k_block_factor, a.dtype, out_dtype, dual=dual
         )
 
-    kp = _round_up(k, k_block_factor)
+    kp = _pad_contract(k, k_block_factor)
     np_ = _round_up(n, bn)
     a_p, row_blocks = _grouped_row_pad(a, group_sizes, bm, kp)
     if a_p is None:
@@ -2010,12 +2029,12 @@ def _grouped_impl(
         dtype_bytes = jnp.dtype(a.dtype).itemsize
         out_bytes = jnp.dtype(out_dtype).itemsize
         while k_block_factor < max(k, 1) and not fused_path_fits_vmem(
-            bm, bn, _round_up(k, k_block_factor) // k_block_factor,
+            bm, bn, _pad_contract(k, k_block_factor) // k_block_factor,
             dtype_bytes, out_bytes, glu=glu,
         ):
             k_block_factor *= 2
 
-    kp = _round_up(k, k_block_factor)
+    kp = _pad_contract(k, k_block_factor)
     np_ = _round_up(n, bn)
 
     # pad each group's rows to a bm multiple and concatenate (host loop:

@@ -12,10 +12,11 @@ schedule compiler (`core.schedule.attention_spec` →
   * consecutive tasks share panels: within a band row the q (or k) panel
     is revisited task after task, and the boustrophedon row turns share
     one k (or q) panel — the BRGEMM₁/₂ structure of the GEMM traversal;
-  * operands are read in the model's native ``(B, S, H, D)`` layout
-    through the index maps — no head transpose, and GQA is resolved by
-    the maps too (a q head reads kv head ``h // group``), so grouped K/V
-    are never `jnp.repeat`-expanded in HBM.
+  * operands are read in the model's native ``(B, S, H, D)`` layout,
+    viewed as ``(B, S, H·D)`` so that one head is one ``(chunk, D)`` lane
+    block (`_heads_on_lanes`) — no head transpose, and GQA is resolved by
+    the index maps too (a q head reads kv head ``h // group``), so grouped
+    K/V are never `jnp.repeat`-expanded in HBM.
 
 Three kernel families:
 
@@ -59,7 +60,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.schedule import attention_spec, compile_schedule
-from repro.kernels.pallas_compat import CompilerParams as _CompilerParams
 
 __all__ = [
     "build_attention_task_table",
@@ -133,6 +133,22 @@ def _tile_mask(
     return valid
 
 
+def _heads_on_lanes(x: jax.Array) -> jax.Array:
+    """(B, S, H, D) -> (B, S, H*D), a free view of the native layout.
+
+    Mosaic tiles the last two dimensions of a block, so a one-head block of
+    (B, S, H, D) — second-minor extent 1 against H — is refused.  On this
+    view one head is the lane block (1, chunk, D) at block index h."""
+    b, s, h, d = x.shape
+    return x.reshape(b, s, h * d)
+
+
+def _stats_by_head(x: jax.Array) -> jax.Array:
+    """(B, S, H, 1) row statistics -> (B, H, S, 1), so that one head's
+    (chunk, 1) column is a legal block; the inverse is the same swap."""
+    return jnp.swapaxes(x, 1, 2)
+
+
 # ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
@@ -140,11 +156,11 @@ def _tile_mask(
 
 def _flash_fwd_kernel(
     tab_ref,  # (4, T) band task table
-    q_ref,  # (1, qc, 1, D)
-    k_ref,  # (1, kc, 1, D)
-    v_ref,  # (1, kc, 1, D)
-    o_ref,  # (1, qc, 1, D)
-    lse_ref,  # (1, qc, 1, 1) f32
+    q_ref,  # (1, qc, D)
+    k_ref,  # (1, kc, D)
+    v_ref,  # (1, kc, D)
+    o_ref,  # (1, qc, D)
+    lse_ref,  # (1, 1, qc, 1) f32
     acc_ref,  # (qc, D) f32
     m_ref,  # (qc, 1) f32
     l_ref,  # (qc, 1) f32
@@ -166,9 +182,9 @@ def _flash_fwd_kernel(
         m_ref[...] = jnp.full_like(m_ref, NEG)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    q = q_ref[0, :, 0, :].astype(jnp.float32) * scale
-    k = k_ref[0, :, 0, :].astype(jnp.float32)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)
+    q = q_ref[0].astype(jnp.float32) * scale
+    k = k_ref[0].astype(jnp.float32)
+    v = v_ref[0].astype(jnp.float32)
     s = lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     )  # (qc, kc)
@@ -190,8 +206,8 @@ def _flash_fwd_kernel(
     @pl.when(tab_ref[3, t] == 1)
     def _flush():
         l = jnp.maximum(l_ref[...], _TINY)
-        o_ref[0, :, 0, :] = (acc_ref[...] / l).astype(o_ref.dtype)
-        lse_ref[0, :, 0, :] = m_ref[...] + jnp.log(l)
+        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
+        lse_ref[0, 0] = m_ref[...] + jnp.log(l)
 
 
 @functools.partial(
@@ -249,22 +265,25 @@ def sfc_flash_fwd(
     )
 
     def q_map(i, t, tab):
-        return (i // h, maj(tab, t), i % h, 0)
+        return (i // h, maj(tab, t), i % h)
 
     def kv_map(i, t, tab):
-        return (i // h, mnr(tab, t), (i % h) // groups, 0)
+        return (i // h, mnr(tab, t), (i % h) // groups)
+
+    def stat_map(i, t, tab):
+        return (i // h, i % h, maj(tab, t), 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(b * h, tab.shape[1]),
         in_specs=[
-            pl.BlockSpec((1, q_chunk, 1, d), q_map),
-            pl.BlockSpec((1, k_chunk, 1, d), kv_map),
-            pl.BlockSpec((1, k_chunk, 1, d), kv_map),
+            pl.BlockSpec((1, q_chunk, d), q_map),
+            pl.BlockSpec((1, k_chunk, d), kv_map),
+            pl.BlockSpec((1, k_chunk, d), kv_map),
         ],
         out_specs=[
-            pl.BlockSpec((1, q_chunk, 1, d), q_map),
-            pl.BlockSpec((1, q_chunk, 1, 1), q_map),
+            pl.BlockSpec((1, q_chunk, d), q_map),
+            pl.BlockSpec((1, 1, q_chunk, 1), stat_map),
         ],
         scratch_shapes=[
             pltpu.VMEM((q_chunk, d), jnp.float32),
@@ -272,18 +291,19 @@ def sfc_flash_fwd(
             pltpu.VMEM((q_chunk, 1), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    o, lse = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((b, sq_p, h, d), q.dtype),
-            jax.ShapeDtypeStruct((b, sq_p, h, 1), jnp.float32),
+            jax.ShapeDtypeStruct((b, sq_p, h * d), q.dtype),
+            jax.ShapeDtypeStruct((b, h, sq_p, 1), jnp.float32),
         ],
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
-    )(tab, q, k, v)
+    )(tab, _heads_on_lanes(q), _heads_on_lanes(k), _heads_on_lanes(v))
+    return o.reshape(b, sq_p, h, d), _stats_by_head(lse)
 
 
 # ---------------------------------------------------------------------------
@@ -310,13 +330,13 @@ def _bwd_p_ds(q, k, v, do, lse, delta, valid, *, scale: float):
 
 def _flash_bwd_dq_kernel(
     tab_ref,
-    q_ref,  # (1, qc, 1, D)
-    k_ref,  # (1, kc, 1, D)
-    v_ref,  # (1, kc, 1, D)
-    do_ref,  # (1, qc, 1, D)
-    lse_ref,  # (1, qc, 1, 1)
-    delta_ref,  # (1, qc, 1, 1)
-    dq_ref,  # (1, qc, 1, D) f32
+    q_ref,  # (1, qc, D)
+    k_ref,  # (1, kc, D)
+    v_ref,  # (1, kc, D)
+    do_ref,  # (1, qc, D)
+    lse_ref,  # (1, 1, qc, 1)
+    delta_ref,  # (1, 1, qc, 1)
+    dq_ref,  # (1, qc, D) f32
     acc_ref,  # (qc, D) f32
     *,
     scale: float,
@@ -337,14 +357,14 @@ def _flash_bwd_dq_kernel(
     valid = _tile_mask(
         iq, ik, q_chunk, k_chunk, seq_q, seq_k, causal, q_offset
     )
-    k = k_ref[0, :, 0, :].astype(jnp.float32)
+    k = k_ref[0].astype(jnp.float32)
     _, ds = _bwd_p_ds(
-        q_ref[0, :, 0, :].astype(jnp.float32),
+        q_ref[0].astype(jnp.float32),
         k,
-        v_ref[0, :, 0, :].astype(jnp.float32),
-        do_ref[0, :, 0, :].astype(jnp.float32),
-        lse_ref[0, :, 0, :],
-        delta_ref[0, :, 0, :],
+        v_ref[0].astype(jnp.float32),
+        do_ref[0].astype(jnp.float32),
+        lse_ref[0, 0],
+        delta_ref[0, 0],
         valid,
         scale=scale,
     )
@@ -354,7 +374,7 @@ def _flash_bwd_dq_kernel(
 
     @pl.when(tab_ref[3, t] == 1)
     def _flush():
-        dq_ref[0, :, 0, :] = acc_ref[...]
+        dq_ref[0] = acc_ref[...]
 
 
 def _flash_bwd_dkv_kernel(
@@ -365,8 +385,8 @@ def _flash_bwd_dkv_kernel(
     do_ref,
     lse_ref,
     delta_ref,
-    dk_ref,  # (1, kc, 1, D) f32
-    dv_ref,  # (1, kc, 1, D) f32
+    dk_ref,  # (1, kc, D) f32
+    dv_ref,  # (1, kc, D) f32
     dk_acc,  # (kc, D) f32
     dv_acc,  # (kc, D) f32
     *,
@@ -390,15 +410,15 @@ def _flash_bwd_dkv_kernel(
     valid = _tile_mask(
         iq, ik, q_chunk, k_chunk, seq_q, seq_k, causal, q_offset
     )
-    q = q_ref[0, :, 0, :].astype(jnp.float32)
-    do = do_ref[0, :, 0, :].astype(jnp.float32)
+    q = q_ref[0].astype(jnp.float32)
+    do = do_ref[0].astype(jnp.float32)
     p, ds = _bwd_p_ds(
         q,
-        k_ref[0, :, 0, :].astype(jnp.float32),
-        v_ref[0, :, 0, :].astype(jnp.float32),
+        k_ref[0].astype(jnp.float32),
+        v_ref[0].astype(jnp.float32),
         do,
-        lse_ref[0, :, 0, :],
-        delta_ref[0, :, 0, :],
+        lse_ref[0, 0],
+        delta_ref[0, 0],
         valid,
         scale=scale,
     )
@@ -414,8 +434,8 @@ def _flash_bwd_dkv_kernel(
 
     @pl.when((tab_ref[3, t] == 1) & (g == groups - 1))
     def _flush():
-        dk_ref[0, :, 0, :] = dk_acc[...]
-        dv_ref[0, :, 0, :] = dv_acc[...]
+        dk_ref[0] = dk_acc[...]
+        dv_ref[0] = dv_acc[...]
 
 
 @functools.partial(
@@ -466,37 +486,41 @@ def sfc_flash_bwd_dq(
     )
 
     def q_map(i, t, tab):
-        return (i // h, maj(tab, t), i % h, 0)
+        return (i // h, maj(tab, t), i % h)
 
     def kv_map(i, t, tab):
-        return (i // h, mnr(tab, t), (i % h) // groups, 0)
+        return (i // h, mnr(tab, t), (i % h) // groups)
 
     def stat_map(i, t, tab):
-        return (i // h, maj(tab, t), i % h, 0)
+        return (i // h, i % h, maj(tab, t), 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(b * h, tab.shape[1]),
         in_specs=[
-            pl.BlockSpec((1, q_chunk, 1, d), q_map),
-            pl.BlockSpec((1, k_chunk, 1, d), kv_map),
-            pl.BlockSpec((1, k_chunk, 1, d), kv_map),
-            pl.BlockSpec((1, q_chunk, 1, d), q_map),
-            pl.BlockSpec((1, q_chunk, 1, 1), stat_map),
-            pl.BlockSpec((1, q_chunk, 1, 1), stat_map),
+            pl.BlockSpec((1, q_chunk, d), q_map),
+            pl.BlockSpec((1, k_chunk, d), kv_map),
+            pl.BlockSpec((1, k_chunk, d), kv_map),
+            pl.BlockSpec((1, q_chunk, d), q_map),
+            pl.BlockSpec((1, 1, q_chunk, 1), stat_map),
+            pl.BlockSpec((1, 1, q_chunk, 1), stat_map),
         ],
-        out_specs=pl.BlockSpec((1, q_chunk, 1, d), q_map),
+        out_specs=pl.BlockSpec((1, q_chunk, d), q_map),
         scratch_shapes=[pltpu.VMEM((q_chunk, d), jnp.float32)],
     )
-    return pl.pallas_call(
+    dq = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, sq_p, h, d), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((b, sq_p, h * d), jnp.float32),
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
-    )(tab, q, k, v, do, lse, delta)
+    )(
+        tab, *map(_heads_on_lanes, (q, k, v, do)),
+        _stats_by_head(lse), _stats_by_head(delta),
+    )
+    return dq.reshape(b, sq_p, h, d)
 
 
 @functools.partial(
@@ -554,43 +578,50 @@ def sfc_flash_bwd_dkv(
     )
 
     def q_map(i, t, g, tab):
-        return (i // hkv, mnr(tab, t), (i % hkv) * groups + g, 0)
+        return (i // hkv, mnr(tab, t), (i % hkv) * groups + g)
 
     def kv_map(i, t, g, tab):
-        return (i // hkv, maj(tab, t), i % hkv, 0)
+        return (i // hkv, maj(tab, t), i % hkv)
+
+    def stat_map(i, t, g, tab):
+        return (i // hkv, (i % hkv) * groups + g, mnr(tab, t), 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(b * hkv, tab.shape[1], groups),
         in_specs=[
-            pl.BlockSpec((1, q_chunk, 1, d), q_map),
-            pl.BlockSpec((1, k_chunk, 1, d), kv_map),
-            pl.BlockSpec((1, k_chunk, 1, d), kv_map),
-            pl.BlockSpec((1, q_chunk, 1, d), q_map),
-            pl.BlockSpec((1, q_chunk, 1, 1), q_map),
-            pl.BlockSpec((1, q_chunk, 1, 1), q_map),
+            pl.BlockSpec((1, q_chunk, d), q_map),
+            pl.BlockSpec((1, k_chunk, d), kv_map),
+            pl.BlockSpec((1, k_chunk, d), kv_map),
+            pl.BlockSpec((1, q_chunk, d), q_map),
+            pl.BlockSpec((1, 1, q_chunk, 1), stat_map),
+            pl.BlockSpec((1, 1, q_chunk, 1), stat_map),
         ],
         out_specs=[
-            pl.BlockSpec((1, k_chunk, 1, d), kv_map),
-            pl.BlockSpec((1, k_chunk, 1, d), kv_map),
+            pl.BlockSpec((1, k_chunk, d), kv_map),
+            pl.BlockSpec((1, k_chunk, d), kv_map),
         ],
         scratch_shapes=[
             pltpu.VMEM((k_chunk, d), jnp.float32),
             pltpu.VMEM((k_chunk, d), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    dk, dv = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((b, sk_p, hkv, d), jnp.float32),
-            jax.ShapeDtypeStruct((b, sk_p, hkv, d), jnp.float32),
+            jax.ShapeDtypeStruct((b, sk_p, hkv * d), jnp.float32),
+            jax.ShapeDtypeStruct((b, sk_p, hkv * d), jnp.float32),
         ],
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
         ),
-    )(tab, q, k, v, do, lse, delta)
+    )(
+        tab, *map(_heads_on_lanes, (q, k, v, do)),
+        _stats_by_head(lse), _stats_by_head(delta),
+    )
+    return dk.reshape(b, sk_p, hkv, d), dv.reshape(b, sk_p, hkv, d)
 
 
 # ---------------------------------------------------------------------------
@@ -601,8 +632,8 @@ def sfc_flash_bwd_dkv(
 def _decode_kernel(
     valid_ref,  # (B,) int32 live cache lengths (scalar prefetch)
     q_ref,  # (1, 1, Gp, D)
-    k_ref,  # (1, kc, 1, D)
-    v_ref,  # (1, kc, 1, D)
+    k_ref,  # (1, kc, D)
+    v_ref,  # (1, kc, D)
     o_ref,  # (1, 1, Gp, D)
     acc_ref,  # (Gp, D) f32
     m_ref,  # (Gp, 1) f32
@@ -629,8 +660,8 @@ def _decode_kernel(
     @pl.when(kc * k_chunk < valid)
     def _accumulate():
         q = q_ref[0, 0].astype(jnp.float32) * scale  # (Gp, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)  # (kc, D)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        k = k_ref[0].astype(jnp.float32)  # (kc, D)
+        v = v_ref[0].astype(jnp.float32)
         s = lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -683,7 +714,7 @@ def sfc_decode_attention_pallas(
     def kv_map(i, kc, valid):
         vb = valid[i // hkv]
         kmax = jnp.maximum((vb + k_chunk - 1) // k_chunk, 1)
-        return (i // hkv, jnp.minimum(kc, kmax - 1), i % hkv, 0)
+        return (i // hkv, jnp.minimum(kc, kmax - 1), i % hkv)
 
     kernel = functools.partial(
         _decode_kernel,
@@ -698,8 +729,8 @@ def sfc_decode_attention_pallas(
         grid=(b * hkv, n_k),
         in_specs=[
             pl.BlockSpec((1, 1, gp, d), q_map),
-            pl.BlockSpec((1, k_chunk, 1, d), kv_map),
-            pl.BlockSpec((1, k_chunk, 1, d), kv_map),
+            pl.BlockSpec((1, k_chunk, d), kv_map),
+            pl.BlockSpec((1, k_chunk, d), kv_map),
         ],
         out_specs=pl.BlockSpec((1, 1, gp, d), q_map),
         scratch_shapes=[
@@ -713,7 +744,7 @@ def sfc_decode_attention_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, gp, d), q.dtype),
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
         ),
-    )(valid_len.astype(jnp.int32), q, k, v)
+    )(valid_len.astype(jnp.int32), q, _heads_on_lanes(k), _heads_on_lanes(v))

@@ -66,7 +66,6 @@ from repro.core.schedule import (
     grouped_gemm_spec,
     grouped_tn_spec,
 )
-from repro.kernels.pallas_compat import CompilerParams as _CompilerParams
 from repro.optim.adamw import (
     HYP_B1,
     HYP_B1C,
@@ -83,6 +82,11 @@ from repro.optim.adamw import (
     seed_from_lane,
 )
 
+# Mosaic's scoped-VMEM limit, set explicitly on every GEMM launch and used
+# by `ops.fused_path_fits_vmem` as the planning budget, so the knob planner
+# and the compiler hold a kernel to the same number.
+VMEM_LIMIT_BYTES = 16 * 2**20
+
 # bumped when a kernel change invalidates measured knobs / calibration
 # constants; `repro.tune.cache` stamps persisted entries with it and
 # drops stale generations on mismatch
@@ -90,6 +94,7 @@ KERNEL_VERSION = 2  # v2: ABFT checksum lane in the fused/TN flush paths
 
 __all__ = [
     "KERNEL_VERSION",
+    "VMEM_LIMIT_BYTES",
     "sfc_gemm_pallas",
     "sfc_gemm_batched",
     "sfc_gemm_fused",
@@ -331,8 +336,9 @@ def _fused_call(
         grid_spec=grid_spec,
         out_shape=out_shapes,
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",) * len(grid),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES,
         ),
     )(tab, *inputs)
 
@@ -724,8 +730,9 @@ def sfc_gemm_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((k_layers, m, n), out_dtype),
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES,
         ),
     )(tab, a, b)
 
@@ -859,8 +866,9 @@ def sfc_gemm_batched(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((bsz, k_layers, m, n), out_dtype),
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES,
         ),
     )(tab, a, b)
 
@@ -1302,8 +1310,9 @@ def sfc_gemm_nt(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",) * 3,
+            vmem_limit_bytes=VMEM_LIMIT_BYTES,
         ),
     )(tab, *inputs)
 
@@ -1586,8 +1595,9 @@ def sfc_gemm_tn(
         grid_spec=grid_spec,
         out_shape=out_shapes,
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",) * 3,
+            vmem_limit_bytes=VMEM_LIMIT_BYTES,
         ),
     )(*prefetch, *inputs)
 
@@ -1728,8 +1738,9 @@ def sfc_gemm_grouped_nt(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m_total, n), out_dtype),
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES,
         ),
     )(tab, *inputs)
 
@@ -2019,8 +2030,9 @@ def sfc_gemm_grouped_tn(
         grid_spec=grid_spec,
         out_shape=out_shapes,
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES,
         ),
     )(*prefetch, *inputs)
 

@@ -50,9 +50,12 @@ from repro.parallel.sharding import (
     make_shardings,
     spec_for_tree,
 )
-from repro.roofline.analysis import model_flops, roofline_terms
+from repro.roofline.analysis import model_flops, roofline_hw, roofline_terms
 from repro.roofline.hlo_cost import module_cost
 from repro.train.step import make_train_step
+
+# the deployment the dry-run models: production meshes of TPU v5e chips
+TARGET_DEVICE_KIND = "TPU v5 lite"
 
 SKIPS: Dict[tuple, str] = {}
 for _arch in ARCH_IDS:
@@ -201,7 +204,8 @@ def lower_cell(
         "per_op_counts": c.coll_counts,
     }
     terms = roofline_terms(
-        {"flops": c.flops, "bytes accessed": c.bytes}, coll, n_chips=n_chips
+        {"flops": c.flops, "bytes accessed": c.bytes}, coll, n_chips=n_chips,
+        hw=roofline_hw(TARGET_DEVICE_KIND),
     )
     mf = model_flops(cfg, shape)
 

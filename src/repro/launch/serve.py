@@ -12,6 +12,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.registry import build_model
 from repro.serving.engine import ServingEngine
 
@@ -27,6 +28,7 @@ def main():
     ap.add_argument("--backend", default="xla", choices=["xla", "sfc_pallas", "sfc_reference"])
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -35,7 +37,9 @@ def main():
         raise SystemExit("enc-dec serving demo: use examples/serve_batched.py")
 
     model = build_model(cfg)
-    params = model.init(jax.random.PRNGKey(args.seed))
+    # jitted: the random draws and the cast to param_dtype fuse, so no f32
+    # copy of a full-width model is ever resident
+    params = jax.jit(model.init)(jax.random.PRNGKey(args.seed))
     engine = ServingEngine(
         cfg,
         params,
@@ -54,7 +58,8 @@ def main():
     print(
         f"[serve] backend={args.backend} n={rep['n_requests']} "
         f"ttft={rep['ttft_mean_s']*1e3:.1f}ms latency={rep['latency_mean_s']*1e3:.1f}ms "
-        f"throughput={rep['tokens_per_s']:.1f} tok/s"
+        f"throughput={rep['tokens_per_s']:.1f} tok/s "
+        f"fallback_calls={engine.degradation_report()['fallback_calls']}"
     )
 
 

@@ -22,6 +22,7 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.data.synthetic import SyntheticLM, SyntheticLMConfig
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_mesh_for
 from repro.models.registry import build_model
 from repro.optim.adamw import AdamWConfig, adamw_init
@@ -140,6 +141,7 @@ def main():
              "(train-step spans, [ft] event counters, tune/ladder series)",
     )
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

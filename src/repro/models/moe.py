@@ -213,7 +213,6 @@ def _moe_shard_map(
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """Explicit EP exchange: local routing -> all_to_all(E->shards) ->
     local expert GEMMs -> reverse all_to_all -> local combine."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh, dp, tp = policy.mesh, policy.dp, policy.tp
@@ -264,7 +263,7 @@ def _moe_shard_map(
         z_loss = router_z_weight * z_part
         return out.reshape(b_loc, s_loc, d), aux_loss, z_loss
 
-    out, aux_loss, z_loss = shard_map(
+    out, aux_loss, z_loss = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(
@@ -275,7 +274,7 @@ def _moe_shard_map(
             P(dp_spec, tp, None),  # x: batch over dp, seq over tp
         ),
         out_specs=(P(dp_spec, tp, None), P(), P()),
-        check_rep=False,
+        check_vma=False,
     )(params["router"], params["w_in"], params["w_gate"], params["w_out"], x)
     return out, {"moe_aux_loss": aux_loss[()] if aux_loss.ndim else aux_loss,
                  "moe_z_loss": z_loss[()] if z_loss.ndim else z_loss}

@@ -21,7 +21,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 __all__ = ["pipeline_apply", "stage_params_spec"]
@@ -95,10 +94,10 @@ def pipeline_apply(
         return outs
 
     p_specs = jax.tree.map(lambda l: stage_params_spec(axis)(l), stage_params)
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(p_specs, data_spec),
         out_specs=data_spec,
-        check_rep=False,
+        check_vma=False,
     )(stage_params, x)

@@ -13,9 +13,9 @@ are NOT in cost_analysis, so we parse the optimized HLO text and sum operand
 sizes of all-gather / all-reduce / reduce-scatter / all-to-all /
 collective-permute ops.
 
-Hardware constants (TPU v5e, from the task spec): 197 TFLOP/s bf16,
-819 GB/s HBM, ~50 GB/s/link ICI (we credit 3 usable link-pairs per chip on a
-2D torus mesh slice: conservative 3 * 50 GB/s aggregate).
+Hardware constants come from the published-peak table keyed by device kind
+(`roofline_hw`); collectives are credited 3 usable link-pairs per chip on a
+2D torus mesh slice (conservative 3 x link bandwidth aggregate).
 """
 
 from __future__ import annotations
@@ -28,24 +28,30 @@ from typing import Any, Dict, List, Optional, Tuple
 import numpy as np
 
 __all__ = [
-    "HW",
+    "roofline_hw",
     "collective_bytes",
     "roofline_terms",
     "train_gemm_roofline_terms",
     "model_flops",
 ]
 
-PEAK_FLOPS = 197e12  # bf16 / chip
-HBM_BW = 819e9  # bytes/s
-ICI_LINK_BW = 50e9  # bytes/s per link (task spec "~50 GB/s/link")
 ICI_LINKS = 3  # usable links per chip credited for collectives
 
-HW = {
-    "peak_flops": PEAK_FLOPS,
-    "hbm_bw": HBM_BW,
-    "ici_link_bw": ICI_LINK_BW,
-    "ici_links": ICI_LINKS,
-}
+
+def roofline_hw(device_kind: str) -> Dict[str, float]:
+    """Roofline constants of one chip, from the published-peak table
+    `core.perf_model.DEVICE_PEAKS` (raises `KeyError` for a kind it does
+    not hold)."""
+    from repro.core.perf_model import device_peaks
+
+    peaks = device_peaks(device_kind)
+    return {
+        "peak_flops": peaks.peak_flops,
+        "hbm_bw": peaks.mem_bw,
+        "ici_link_bw": 1.0 / peaks.ici_beta,
+        "ici_links": ICI_LINKS,
+    }
+
 
 _DTYPE_BYTES = {
     "pred": 1,
@@ -139,7 +145,7 @@ def roofline_terms(
     coll: Dict[str, float],
     *,
     n_chips: int,
-    hw: Dict[str, float] = HW,
+    hw: Dict[str, float],
 ) -> Dict[str, float]:
     """The three §Roofline terms, in seconds (per chip / per step)."""
     flops = float(cost.get("flops", 0.0))
@@ -173,7 +179,7 @@ def train_gemm_roofline_terms(
     K: int,
     *,
     dtype_bytes: int = 2,
-    hw: Dict[str, float] = HW,
+    hw: Dict[str, float],
 ) -> Dict[str, float]:
     """Per-chip roofline terms for one projection's *train* step: the
     forward GEMM plus both backward GEMMs (dA = dC·Bᵀ, dB = Aᵀ·dC).

@@ -114,8 +114,17 @@ class ServingEngine:
         Pallas rungs of every namespace this engine routes (shape ``None``
         = whole rung), drop the jit caches so the next trace picks the
         fallback rungs, and retry once.  Unclassified errors propagate —
-        self-healing covers platform breakage, not bugs."""
-        from repro.robust import PALLAS_RUNGS, classify_failure, get_registry
+        self-healing covers platform breakage, not bugs.  Under
+        ``REPRO_STRICT=1`` a classified failure that was not injected
+        raises `StrictFallbackError` instead: a strict run never serves
+        from a rung it did not ask for."""
+        from repro.robust import (
+            PALLAS_RUNGS,
+            StrictFallbackError,
+            classify_failure,
+            get_registry,
+            strict_mode,
+        )
         from repro.robust.inject import InjectedFault
 
         try:
@@ -124,8 +133,13 @@ class ServingEngine:
             kind = classify_failure(exc)
             if kind is None:
                 raise
-            reg = get_registry()
             injected = isinstance(exc, InjectedFault)
+            if strict_mode() and not injected:
+                raise StrictFallbackError(
+                    f"REPRO_STRICT: engine program {which!r} failed "
+                    f"({kind}); refusing to re-trace on fallback rungs: {exc}"
+                ) from exc
+            reg = get_registry()
             for namespace in self._LADDER_NAMESPACES:
                 for rung in PALLAS_RUNGS:
                     reg.quarantine(
@@ -305,11 +319,7 @@ class ServingEngine:
             # fit the per-device platform constants once so the predictive
             # ranking below is calibrated, not datasheet guesswork (a
             # pure cache read after the first warmup on this device)
-            try:
-                calibrate()
-            except Exception:
-                # tuning still works uncalibrated (datasheet ranking)
-                pass
+            calibrate()
             # key the cache by the dtype the projections will actually trace
             # with (activations follow param_dtype), or the lookup misses
             dtype = jnp.dtype(self.cfg.param_dtype)

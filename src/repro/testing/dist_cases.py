@@ -27,18 +27,20 @@ def case_ca_matmul():
 
 def case_ca_matmul_backends():
     import jax, jax.numpy as jnp
-    from repro.core.ca_matmul import ca_matmul
+    from repro.core.ca_matmul import ca_matmul, summa_ca_matmul
 
     mesh = jax.make_mesh((2, 2, 2), ("kl", "tm", "tn"))
     rng = np.random.default_rng(1)
     a = jnp.asarray(rng.normal(size=(32, 64)), jnp.float32)
     b = jnp.asarray(rng.normal(size=(64, 32)), jnp.float32)
     want = np.asarray(a) @ np.asarray(b)
+    axes = dict(mesh=mesh, tm_axis="tm", tn_axis="tn", kl_axis="kl")
     for backend in ("xla", "sfc_reference", "sfc_pallas"):
-        got = ca_matmul(
-            a, b, mesh=mesh, tm_axis="tm", tn_axis="tn", kl_axis="kl", backend=backend
-        )
-        np.testing.assert_allclose(np.asarray(got), want, rtol=1e-3, atol=1e-3)
+        for fn in (ca_matmul, summa_ca_matmul):
+            got = fn(a, b, backend=backend, **axes)
+            np.testing.assert_allclose(
+                np.asarray(got), want, rtol=1e-3, atol=1e-3
+            )
 
 
 def case_sharded_train_step():
@@ -92,7 +94,6 @@ def case_elastic_reshard():
 def case_compressed_gradient_sync():
     """Error-feedback int8 sync over a mesh axis: converges like f32."""
     import jax, jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.optim.compression import compressed_psum_mean
 
@@ -103,9 +104,9 @@ def case_compressed_gradient_sync():
     def body(g_loc):
         return compressed_psum_mean(g_loc, "pod")
 
-    synced = shard_map(
+    synced = jax.shard_map(
         body, mesh=mesh, in_specs=P("pod", None), out_specs=P("pod", None),
-        check_rep=False,
+        check_vma=False,
     )(g)
     # each pod row receives the mean of all 4 shards (up to int8 quantization)
     want = np.asarray(g).reshape(4, 2, 32).mean(axis=0)

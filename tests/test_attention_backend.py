@@ -25,6 +25,7 @@ from repro.core import attention_backend as ab
 from repro.kernels.ref import flash_attention_ref
 from repro.kernels.sfc_attention import build_attention_task_table
 from repro.models.layers import decode_attention as decode_ref
+from repro.testing.jaxpr import op_census
 
 
 def _rand(*shape, dtype=jnp.float32, seed=0):
@@ -40,34 +41,8 @@ def _qkv(b, s, t, h, hkv, d, dtype=jnp.float32, seed=0):
     )
 
 
-def _census(jaxpr, counts):
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pallas_call":
-            counts["pallas"] += 1
-            continue
-        if eqn.primitive.name == "dot_general":
-            counts["dot"] += 1
-            counts["dot_shapes"].append(
-                tuple(tuple(v.aval.shape) for v in eqn.invars)
-            )
-        for val in eqn.params.values():
-            _census_param(val, counts)
-    return counts
-
-
-def _census_param(val, counts):
-    if isinstance(val, jax.core.ClosedJaxpr):
-        _census(val.jaxpr, counts)
-    elif isinstance(val, jax.core.Jaxpr):
-        _census(val, counts)
-    elif isinstance(val, (tuple, list)):
-        for v in val:
-            _census_param(v, counts)
-
-
 def _count(fn, *args):
-    jx = jax.make_jaxpr(fn)(*args)
-    return _census(jx.jaxpr, {"dot": 0, "pallas": 0, "dot_shapes": []})
+    return op_census(jax.make_jaxpr(fn)(*args).jaxpr)
 
 
 # ---------------------------------------------------------------------------
@@ -397,7 +372,7 @@ def test_train_step_jaxpr_is_dot_general_free():
     step = make_train_step(
         model, AdamWConfig(lr=1e-3), remat="none", backend=BackendConfig(gemm_backend="sfc_pallas"))
     jx = jax.make_jaxpr(step)(params, adamw_init(params), batch)
-    c = _census(jx.jaxpr, {"dot": 0, "pallas": 0, "dot_shapes": []})
+    c = op_census(jx.jaxpr)
     assert c["pallas"] > 0
     assert c["dot"] == 0, (
         f"dot_general survived the SFC train step: {c['dot_shapes']}"

@@ -15,6 +15,7 @@ import pytest
 
 from repro.core import gemm_backend as gb
 from repro.core import namespaces as ns
+from repro.testing.jaxpr import op_census
 
 SIGNATURES = {
     "blhp,bjhp->bljh": ((2, 24, 3, 16), (2, 24, 3, 16)),
@@ -33,31 +34,8 @@ def _operands(subs, seed=0):
     )
 
 
-def _census(jaxpr, counts):
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pallas_call":
-            counts["pallas"] += 1
-            continue
-        if eqn.primitive.name == "dot_general":
-            counts["dot"] += 1
-        for val in eqn.params.values():
-            _census_param(val, counts)
-    return counts
-
-
-def _census_param(val, counts):
-    if isinstance(val, jax.core.ClosedJaxpr):
-        _census(val.jaxpr, counts)
-    elif isinstance(val, jax.core.Jaxpr):
-        _census(val, counts)
-    elif isinstance(val, (tuple, list)):
-        for v in val:
-            _census_param(v, counts)
-
-
 def _count(fn, *args):
-    jx = jax.make_jaxpr(fn)(*args)
-    return _census(jx.jaxpr, {"dot": 0, "pallas": 0})
+    return op_census(jax.make_jaxpr(fn)(*args).jaxpr)
 
 
 # ---------------------------------------------------------------------------

@@ -14,6 +14,7 @@ from repro.kernels.ops import (
     sfc_grouped_matmul,
     sfc_matmul,
 )
+from repro.testing.jaxpr import walk_eqns
 
 
 def _rand(*shape, dtype=jnp.float32, seed=0):
@@ -78,32 +79,15 @@ def _close(got, want, dtype, msg=""):
 # ---------------------------------------------------------------------------
 
 
-def _walk_jaxpr(jaxpr, pallas_eqns, shapes):
-    for eqn in jaxpr.eqns:
+def _trace(fn, *args):
+    pallas_eqns, shapes = [], []
+    for eqn in walk_eqns(jax.make_jaxpr(fn)(*args).jaxpr):
         if eqn.primitive.name == "pallas_call":
             pallas_eqns.append(eqn)
         for v in eqn.outvars:
             aval = getattr(v, "aval", None)
             if aval is not None and hasattr(aval, "shape"):
                 shapes.append(tuple(aval.shape))
-        for val in eqn.params.values():
-            _walk_param(val, pallas_eqns, shapes)
-
-
-def _walk_param(val, pallas_eqns, shapes):
-    if isinstance(val, jax.core.ClosedJaxpr):
-        _walk_jaxpr(val.jaxpr, pallas_eqns, shapes)
-    elif isinstance(val, jax.core.Jaxpr):
-        _walk_jaxpr(val, pallas_eqns, shapes)
-    elif isinstance(val, (tuple, list)):
-        for v in val:
-            _walk_param(v, pallas_eqns, shapes)
-
-
-def _trace(fn, *args):
-    jaxpr = jax.make_jaxpr(fn)(*args)
-    pallas_eqns, shapes = [], []
-    _walk_jaxpr(jaxpr.jaxpr, pallas_eqns, shapes)
     return pallas_eqns, shapes
 
 

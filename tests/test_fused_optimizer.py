@@ -33,6 +33,7 @@ from repro.optim.adamw import (
     seed_to_lane,
 )
 from repro.optim.fused import probe_routed
+from repro.testing.jaxpr import walk_eqns
 from repro.train.step import BackendConfig, make_train_step
 
 
@@ -452,18 +453,15 @@ def test_fused_step_legacy_gnorm_state_still_accepted(mini):
     assert jax.tree_util.tree_structure(s2) == jax.tree_util.tree_structure(s1)
 
 
-def _count_elementwise_at_shape(jaxpr, shape, counts=None):
+def _count_elementwise_at_shape(jaxpr, shape):
     """Count non-pallas elementwise eqns whose every in/outvar has `shape`
     — the signature of a standalone optimizer pass over a routed weight."""
     elementwise = {
         "add", "sub", "mul", "div", "sqrt", "rsqrt", "integer_pow",
         "max", "min",
     }
-    if counts is None:
-        counts = {"n": 0}
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pallas_call":
-            continue
+    n = 0
+    for eqn in walk_eqns(jaxpr):
         shapes = [tuple(v.aval.shape) for v in (*eqn.invars, *eqn.outvars)
                   if hasattr(v, "aval")]
         if (
@@ -471,20 +469,8 @@ def _count_elementwise_at_shape(jaxpr, shape, counts=None):
             and shapes
             and all(s == shape for s in shapes)
         ):
-            counts["n"] += 1
-        for val in eqn.params.values():
-            _walk_param(val, shape, counts)
-    return counts
-
-
-def _walk_param(val, shape, counts):
-    if isinstance(val, jax.core.ClosedJaxpr):
-        _count_elementwise_at_shape(val.jaxpr, shape, counts)
-    elif isinstance(val, jax.core.Jaxpr):
-        _count_elementwise_at_shape(val, shape, counts)
-    elif isinstance(val, (tuple, list)):
-        for v in val:
-            _walk_param(v, shape, counts)
+            n += 1
+    return {"n": n}
 
 
 def test_fused_step_jaxpr_has_no_optimizer_pass_for_routed_weights(mini):

@@ -21,6 +21,7 @@ from repro.kernels.ops import (
     sfc_matmul_nt,
     sfc_matmul_tn,
 )
+from repro.testing.jaxpr import op_census
 
 
 def _rand(*shape, dtype=jnp.float32, seed=0):
@@ -46,37 +47,9 @@ def _grads_close(got, want, dtype, msg=""):
 # ---------------------------------------------------------------------------
 
 
-def _census(jaxpr, counts):
-    """Count dot_general eqns OUTSIDE pallas_call kernels (interpret-mode
-    pallas params contain the kernel jaxpr — on TPU that is Mosaic, so
-    kernel-internal dots are the SFC path, not a fallback)."""
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pallas_call":
-            counts["pallas"] += 1
-            continue
-        if eqn.primitive.name == "dot_general":
-            counts["dot"] += 1
-            counts["dot_shapes"].append(
-                tuple(tuple(v.aval.shape) for v in eqn.invars)
-            )
-        for val in eqn.params.values():
-            _census_param(val, counts)
-    return counts
-
-
-def _census_param(val, counts):
-    if isinstance(val, jax.core.ClosedJaxpr):
-        _census(val.jaxpr, counts)
-    elif isinstance(val, jax.core.Jaxpr):
-        _census(val, counts)
-    elif isinstance(val, (tuple, list)):
-        for v in val:
-            _census_param(v, counts)
-
-
 def _grad_census(fn, *args):
     jx = jax.make_jaxpr(jax.grad(fn, argnums=tuple(range(len(args)))))(*args)
-    return _census(jx.jaxpr, {"dot": 0, "pallas": 0, "dot_shapes": []})
+    return op_census(jx.jaxpr)
 
 
 def test_matmul_backward_is_sfc_kernels():
@@ -382,7 +355,7 @@ def test_train_step_backward_no_projection_dot_general():
     step = make_train_step(
         model, opt_cfg, remat="none", backend=BackendConfig(gemm_backend="sfc_pallas"))
     jx = jax.make_jaxpr(step)(params, opt_state, batch)
-    c = _census(jx.jaxpr, {"dot": 0, "pallas": 0, "dot_shapes": []})
+    c = op_census(jx.jaxpr)
     assert c["pallas"] > 0, "sfc backend did not launch any SFC kernels"
     rank2 = [
         shp for shp in c["dot_shapes"] if any(len(op) <= 2 for op in shp)

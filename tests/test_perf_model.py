@@ -122,3 +122,17 @@ else:
     @settings(max_examples=10, deadline=None)
     def test_simulated_throughput_bounded_by_roofline(m, n, k):
         _check_throughput_bounded(m, n, k)
+
+
+def test_device_peaks_keyed_by_device_kind():
+    """Published peaks are read by `device_kind`; a kind without a row is
+    an error, never another chip's numbers."""
+    from repro.core.perf_model import device_peaks
+    from repro.roofline.analysis import roofline_hw
+
+    v5e = device_peaks("TPU v5 lite")
+    assert v5e.peak_flops == 197e12 and v5e.mem_bw == 819e9
+    assert roofline_hw("TPU v5 lite")["peak_flops"] == 197e12
+    for lookup in (device_peaks, roofline_hw):
+        with pytest.raises(KeyError, match="TPU v9"):
+            lookup("TPU v9")

@@ -242,3 +242,34 @@ def test_sampled_verification_detection_redoes_step(small_model):
     assert "gemm" in reg.quarantined_namespaces()
     assert {r["reason"] for r in reg.export_state().values()} == {"sdc"}
     abft.reset_runtime_sdc()
+
+
+@pytest.mark.parametrize("strict", [False, True])
+def test_engine_runtime_failure_heals_unless_strict(small_model, monkeypatch,
+                                                     strict):
+    """A classified failure of a whole compiled program (what the trace-time
+    ladder cannot see) re-traces on fallback rungs, except under
+    REPRO_STRICT=1, where it raises and quarantines nothing."""
+    from repro.robust import StrictFallbackError, get_registry
+
+    if strict:
+        monkeypatch.setenv("REPRO_STRICT", "1")
+    else:
+        monkeypatch.delenv("REPRO_STRICT", raising=False)
+    cfg, model, params = small_model
+    engine = ServingEngine(cfg, params, max_batch=2, max_seq=16,
+                           gemm_backend="sfc_pallas")
+
+    def exhausted(*args):
+        raise RuntimeError("RESOURCE_EXHAUSTED: scoped vmem limit exceeded")
+
+    engine._prefill = exhausted
+    tokens = jnp.zeros((2, 8), jnp.int32)
+    if strict:
+        with pytest.raises(StrictFallbackError):
+            engine._run_healed("_prefill", tokens)
+        assert not get_registry().degradation_report()["quarantined"]
+    else:
+        logits, _ = engine._run_healed("_prefill", tokens)
+        assert logits.shape == (2, cfg.vocab)
+        assert get_registry().degradation_report()["quarantined"]

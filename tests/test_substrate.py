@@ -153,3 +153,23 @@ def test_error_feedback_buffers_shapes():
     params = {"a": jnp.zeros((3, 3), jnp.bfloat16)}
     e = ef_init(params)
     assert e["a"].dtype == jnp.float32 and e["a"].shape == (3, 3)
+
+
+def test_compile_cache_dir_from_env_or_repo(monkeypatch):
+    """`JAX_COMPILATION_CACHE_DIR` stands when set; otherwise the cache
+    goes to the fixed <repo>/.jax_cache."""
+    from repro.launch import compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+        assert compile_cache.use_compile_cache() == "/elsewhere/cache"
+        assert jax.config.jax_compilation_cache_dir == before
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = compile_cache.use_compile_cache()
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert path == os.path.join(repo, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
