@@ -32,6 +32,7 @@ from repro.obs.metrics import (
     Gauge,
     Histogram,
     Registry,
+    count_lowerings,
     enabled,
     inc,
     observe,
@@ -58,6 +59,7 @@ __all__ = [
     "set_gauge",
     "observe",
     "snapshot",
+    "count_lowerings",
     "require_series",
     "span",
     "SPAN_NAMES",

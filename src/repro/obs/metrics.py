@@ -49,6 +49,7 @@ __all__ = [
     "set_gauge",
     "observe",
     "snapshot",
+    "count_lowerings",
 ]
 
 _DISABLED_VALUES = ("0", "false", "off", "no")
@@ -326,6 +327,34 @@ def observe(name: str, value: float, **labels) -> None:
 
 def snapshot() -> Dict[str, Dict]:
     return _REGISTRY.snapshot()
+
+
+# JAX records this duration once per lowering of a jaxpr to an MLIR module:
+# every compile, and every read of the persistent compilation cache
+LOWERING_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_LOWERING_LOCK = threading.Lock()
+_LOWERING_LISTENING = False
+
+
+def _on_duration_event(event: str, duration_secs: float, **kwargs) -> None:
+    if event == LOWERING_EVENT:
+        inc("jax.lowerings")
+
+
+def count_lowerings() -> None:
+    """Count JAX lowerings in the ``jax.lowerings`` counter from now on.
+
+    Installs one `jax.monitoring` duration listener per process (a second
+    call does nothing); the gate is read as each event arrives.  In steady
+    serving the counter stays flat: a rise means a recompile."""
+    global _LOWERING_LISTENING
+    with _LOWERING_LOCK:
+        if _LOWERING_LISTENING:
+            return
+        from jax import monitoring
+
+        monitoring.register_event_duration_secs_listener(_on_duration_event)
+        _LOWERING_LISTENING = True
 
 
 def require_series(names: Iterable[str]) -> List[str]:

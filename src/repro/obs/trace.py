@@ -14,8 +14,14 @@ Span taxonomy (the names the stack emits; see README "Observability"):
                          the namespace rides in `ladder.served` counters)
     abft/verify          one checksum comparison
     serving/admission    request batching + overdue shedding
-    serving/prefill      one batched prefill launch
-    serving/decode       one batched decode step
+    serving/prefill      one batched prefill, until its first tokens are
+                         on the host
+    serving/decode       one decode iteration: deadline check, launch,
+                         token sync, then bookkeeping (its self time)
+    serving/launch       the dispatch of a prefill or decode program
+                         (child of serving/prefill or serving/decode)
+    serving/token_sync   argmax and the per-row device->host token reads
+                         (child of serving/prefill or serving/decode)
     serving/retire       end-of-batch request bookkeeping
     train/batch          host-side batch materialization
     train/step           one train_step call (jit dispatch + wait)
@@ -46,6 +52,8 @@ SPAN_NAMES = (
     "serving/admission",
     "serving/prefill",
     "serving/decode",
+    "serving/launch",
+    "serving/token_sync",
     "serving/retire",
     "train/batch",
     "train/step",

@@ -50,8 +50,11 @@ class Request:
     status: str = "pending"  # pending | completed | timed_out
     output: Optional[List[int]] = None
     submitted_at: float = 0.0
-    first_token_at: float = 0.0
-    done_at: float = 0.0
+    first_token_at: float = 0.0  # token_times[0]: the first token on the host
+    done_at: float = 0.0  # a completed request's token_times[-1]
+    # perf_counter time each token of `output` reached the host: one clock
+    # read per prefill/decode step, shared by the rows of that step
+    token_times: List[float] = dataclasses.field(default_factory=list)
 
     def past_deadline(self, now: float) -> bool:
         return (
@@ -93,6 +96,7 @@ class ServingEngine:
         self._decode_steps = 0
         self._verified_steps = 0
         self._sdc_detections = 0
+        obs_metrics.count_lowerings()  # jax.lowerings: recompiles show
 
         self._jit()
         self._uid = 0
@@ -428,69 +432,92 @@ class ServingEngine:
 
             tokens = jnp.asarray(np.stack([r.prompt for r in batch]))
             with span("serving/prefill", batch=len(batch)):
-                logits, cache = self._run_healed("_prefill", tokens)
-            now = time.perf_counter()
-            next_tok = jnp.argmax(logits, axis=-1)[:, None].astype(jnp.int32)
-            # post-prefill deadline check: a long prefill can eat a whole
-            # budget — retire those requests here (no first token emitted)
-            # instead of letting them leak into the decode loop
-            live = []
-            for i, r in enumerate(batch):
-                r.output = []
-                if r.past_deadline(now):
-                    r.status = "timed_out"
-                    r.done_at = now
-                else:
-                    r.first_token_at = now
-                    r.output.append(int(next_tok[i, 0]))
-                    live.append(i)
-
-            steps = max(r.max_new_tokens for r in batch) - 1
-            for _ in range(steps):
-                now = time.perf_counter()
-                for i in list(live):
-                    r = batch[i]
+                with span("serving/launch"):
+                    logits, cache = self._run_healed("_prefill", tokens)
+                next_tok, toks, now = self._sync_tokens(
+                    logits, range(len(batch)), "prefill"
+                )
+                # post-prefill deadline check, at the time the first tokens
+                # reached the host: a long prefill can eat a whole budget —
+                # retire those requests here (no first token emitted)
+                # instead of letting them leak into the decode loop
+                live = []
+                for i, r in enumerate(batch):
+                    r.output = []
                     if r.past_deadline(now):
                         r.status = "timed_out"
                         r.done_at = now
-                        live.remove(i)
+                    else:
+                        r.first_token_at = now
+                        r.output.append(toks[i])
+                        r.token_times.append(now)
+                        live.append(i)
+
+            steps = max(r.max_new_tokens for r in batch) - 1
+            for _ in range(steps):
                 if not live:
                     break
-                self._decode_steps += 1
-                with span("serving/decode", step=self._decode_steps):
-                    if self._verify_every and (
-                        self._decode_steps % self._verify_every == 0
-                    ):
-                        logits, cache = self._verified_decode(next_tok, cache)
-                    else:
-                        logits, cache = self._run_healed(
-                            "_decode", next_tok, cache
-                        )
-                next_tok = jnp.argmax(logits, axis=-1)[:, None].astype(jnp.int32)
-                still = []
-                for i in live:
-                    r = batch[i]
-                    tok = int(next_tok[i, 0])
-                    if len(r.output) < r.max_new_tokens:
-                        r.output.append(tok)
-                    finished = len(r.output) >= r.max_new_tokens or (
-                        eos_id is not None and tok == eos_id
+                with span("serving/decode", step=self._decode_steps + 1):
+                    now = time.perf_counter()
+                    for i in list(live):
+                        r = batch[i]
+                        if r.past_deadline(now):
+                            r.status = "timed_out"
+                            r.done_at = now
+                            live.remove(i)
+                    if not live:
+                        break
+                    self._decode_steps += 1
+                    obs_metrics.inc("serving.decode_steps")
+                    with span("serving/launch"):
+                        if self._verify_every and (
+                            self._decode_steps % self._verify_every == 0
+                        ):
+                            logits, cache = self._verified_decode(
+                                next_tok, cache
+                            )
+                        else:
+                            logits, cache = self._run_healed(
+                                "_decode", next_tok, cache
+                            )
+                    next_tok, toks, now = self._sync_tokens(
+                        logits, live, "decode"
                     )
-                    if finished:
-                        r.status = "completed"
-                        r.done_at = time.perf_counter()
-                    else:
-                        still.append(i)
-                live = still
+                    still = []
+                    for i, tok in zip(live, toks):
+                        r = batch[i]
+                        if len(r.output) < r.max_new_tokens:
+                            r.output.append(tok)
+                            r.token_times.append(now)
+                        finished = len(r.output) >= r.max_new_tokens or (
+                            eos_id is not None and tok == eos_id
+                        )
+                        if finished:
+                            r.status = "completed"
+                            r.done_at = r.token_times[-1]
+                        else:
+                            still.append(i)
+                    live = still
             with span("serving/retire"):
-                now = time.perf_counter()
                 for r in batch:
                     if not r.done_at:
                         r.status = "completed"
-                        r.done_at = now
+                        r.done_at = r.token_times[-1]
                     self._record_retired(r)
                 results.extend(batch)
         return results
+
+    @staticmethod
+    def _sync_tokens(logits, rows, phase: str):
+        """Greedy tokens of one step, brought to the host: the argmax on
+        the device, then one device→host read per row in ``rows``.
+        Returns the device tokens (the next step's input), the rows'
+        tokens as ints, and the time they reached the host."""
+        with span("serving/token_sync"):
+            next_tok = jnp.argmax(logits, axis=-1)[:, None].astype(jnp.int32)
+            toks = [int(next_tok[i, 0]) for i in rows]
+            obs_metrics.inc("serving.host_reads", value=len(toks), phase=phase)
+            return next_tok, toks, time.perf_counter()
 
     # ---------------- metrics ----------------
 
@@ -498,8 +525,8 @@ class ServingEngine:
     def _record_retired(r: Request) -> None:
         """Emit one request's lifecycle into the obs registry.  The same
         quantities `latency_report` summarises — TTFT, end-to-end latency,
-        per-decoded-token latency — recorded as histograms so a fleet gets
-        the p95 without holding Request objects."""
+        every gap between successive tokens — recorded as histograms so a
+        fleet gets the p95 without holding Request objects."""
         obs_metrics.inc("serving." + (
             "timed_out" if r.status == "timed_out" else "completed"
         ))
@@ -517,11 +544,8 @@ class ServingEngine:
             obs_metrics.observe(
                 "serving.e2e_us", (r.done_at - r.submitted_at) * 1e6
             )
-        if r.first_token_at > 0 and n_tok > 1:
-            obs_metrics.observe(
-                "serving.token_us",
-                (r.done_at - r.first_token_at) / (n_tok - 1) * 1e6,
-            )
+        for gap in np.diff(r.token_times):
+            obs_metrics.observe("serving.itl_us", gap * 1e6)
 
     @staticmethod
     def latency_report(requests: List[Request]) -> Dict[str, float]:
@@ -533,8 +557,10 @@ class ServingEngine:
         The p50/p95/p99 tails come from `repro.obs.metrics.Histogram` —
         the same class (and the same sample definitions, see
         `_record_retired`) behind the ``serving.ttft_us`` /
-        ``serving.token_us`` series in the process registry, so this
-        report and a telemetry export never disagree on the math."""
+        ``serving.itl_us`` series in the process registry, so this
+        report and a telemetry export never disagree on the math.  The
+        ``token_*`` tails are over every gap between successive
+        ``token_times`` of a request."""
         zeros = {
             "n_requests": 0,
             "n_timed_out": 0,
@@ -555,12 +581,8 @@ class ServingEngine:
         for r in requests:
             if r.first_token_at > 0:
                 hist.observe(r.first_token_at - r.submitted_at, kind="ttft")
-                n_out = len(r.output or [])
-                if n_out > 1:
-                    hist.observe(
-                        (r.done_at - r.first_token_at) / (n_out - 1),
-                        kind="token",
-                    )
+            for gap in np.diff(r.token_times):
+                hist.observe(gap, kind="token")
         ttft = hist.summary(kind="ttft")
         token = hist.summary(kind="token")
         total = [r.done_at - r.submitted_at for r in requests]

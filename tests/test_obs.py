@@ -145,8 +145,8 @@ def test_span_records_on_exception():
 def test_span_taxonomy_is_documented():
     # every span name the instrumented call sites use must stay on the
     # documented taxonomy (README table + trace.SPAN_NAMES)
-    assert len(obs.SPAN_NAMES) == 11
-    assert len(set(obs.SPAN_NAMES)) == 11
+    assert len(obs.SPAN_NAMES) == 13
+    assert len(set(obs.SPAN_NAMES)) == 13
     for name in obs.SPAN_NAMES:
         assert "/" in name
 
@@ -378,7 +378,9 @@ def test_latency_report_percentiles_consistent_with_obs_store():
         r.status = "completed"
         r.submitted_at = 100.0
         r.first_token_at = 100.0 + ttft
-        r.done_at = r.first_token_at + 0.005 * (n_tok - 1)
+        gaps = rng.uniform(0.002, 0.010, n_tok - 1)
+        r.token_times = list(r.first_token_at + np.r_[0.0, np.cumsum(gaps)])
+        r.done_at = r.token_times[-1]
         r.output = list(range(n_tok))
         reqs.append(r)
         ServingEngine._record_retired(r)
@@ -391,10 +393,31 @@ def test_latency_report_percentiles_consistent_with_obs_store():
     assert rep["ttft_p95_s"] * 1e6 == pytest.approx(store["p95"], rel=1e-9)
     assert rep["ttft_p99_s"] * 1e6 == pytest.approx(store["p99"], rel=1e-9)
     assert rep["ttft_mean_s"] * 1e6 == pytest.approx(store["mean"], rel=1e-9)
-    tok = obs.registry().histogram("serving.token_us").summary()
+    tok = obs.registry().histogram("serving.itl_us").summary()
+    assert tok["count"] == 40 * 7  # every gap between successive tokens
     assert rep["token_p95_s"] * 1e6 == pytest.approx(tok["p95"], rel=1e-9)
     assert obs.registry().counter("serving.completed").total() == 40.0
     assert obs.registry().counter("serving.tokens").total() == 40.0 * 8
+
+
+def test_lowering_counter_counts_compiles_not_cache_hits():
+    import jax
+    import jax.numpy as jnp
+
+    obs.set_enabled(True)
+    obs.count_lowerings()
+    obs.count_lowerings()  # idempotent: one listener, one count per lowering
+    lowerings = obs.registry().counter("jax.lowerings")
+    f = jax.jit(lambda x: x * 3 + 1)
+    a, b = jnp.ones(5), jnp.zeros(5)
+    before = lowerings.total()
+    f(a)
+    assert lowerings.total() - before == 1
+    f(b)  # same shapes: the jit cache serves it, no lowering
+    assert lowerings.total() - before == 1
+    obs.set_enabled(False)  # the gate is read as each event arrives
+    jax.jit(lambda x: x - 2)(a)
+    assert lowerings.total() - before == 1
 
 
 def test_structured_log_counts_and_forwards():

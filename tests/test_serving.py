@@ -273,3 +273,97 @@ def test_engine_runtime_failure_heals_unless_strict(small_model, monkeypatch,
         logits, _ = engine._run_healed("_prefill", tokens)
         assert logits.shape == (2, cfg.vocab)
         assert get_registry().degradation_report()["quarantined"]
+
+
+@pytest.mark.parametrize("obs_on", [True, False], ids=["obs_on", "obs_off"])
+def test_engine_spans_counters_and_token_times(small_model, monkeypatch,
+                                               obs_on):
+    """Each prefill and decode step opens one serving/launch and one
+    serving/token_sync span; the host-read counter counts one read per
+    live row per step; every token gets a host timestamp, with or without
+    observability."""
+    from repro import obs
+
+    monkeypatch.setenv("REPRO_OBS", "1" if obs_on else "0")
+    cfg, model, params = small_model
+    engine = ServingEngine(cfg, params, max_batch=3, max_seq=32)
+    rng = np.random.default_rng(10)
+    prompts = [rng.integers(0, cfg.vocab, size=8).astype(np.int32)
+               for _ in range(5)]
+    reqs = engine.submit_many(prompts, max_new_tokens=4)
+    # rows retire at different steps: live rows per step 3, 2, 2, 1 in
+    # the first batch of three, then 2, 2 in the second
+    for r, n in zip(reqs, (4, 2, 5, 3, 3)):
+        r.max_new_tokens = n
+    done = engine.run(reqs)
+
+    for r in done:
+        assert r.status == "completed"
+        assert len(r.token_times) == len(r.output) == r.max_new_tokens
+        assert r.token_times == sorted(r.token_times)
+        assert r.first_token_at == r.token_times[0] >= r.submitted_at
+        assert r.done_at == r.token_times[-1]
+
+    reg = obs.registry()
+    if not obs_on:
+        assert reg.names() == []
+        return
+    prefills, steps = 2, 4 + 2
+    assert reg.counter("serving.decode_steps").total() == steps
+
+    def spans(name):
+        return reg.histogram(f"span.serving/{name}_us").count()
+
+    assert spans("prefill") == prefills
+    assert spans("decode") == steps
+    assert spans("launch") == spans("token_sync") == prefills + steps
+    reads = reg.counter("serving.host_reads")
+    assert reads.value(phase="prefill") == 5
+    # the sum of live rows over the steps: each request's tokens after
+    # its first
+    assert reads.value(phase="decode") == sum(
+        r.max_new_tokens - 1 for r in reqs) == 3 + 2 + 2 + 1 + 2 + 2
+    itl = reg.histogram("serving.itl_us")
+    assert itl.count() == sum(len(r.output) - 1 for r in done)
+
+
+def test_first_token_time_is_when_tokens_reach_the_host(small_model):
+    """TTFT and the post-prefill deadline check read the time the first
+    tokens are on the host, not the time the prefill was dispatched: here
+    the prefill's logits only materialize when the engine reads them."""
+    import time
+
+    cfg, model, params = small_model
+    engine = ServingEngine(cfg, params, max_batch=2, max_seq=32)
+    rng = np.random.default_rng(11)
+    prompts = [rng.integers(0, cfg.vocab, size=8).astype(np.int32)
+               for _ in range(2)]
+    engine.run(engine.submit_many(prompts, max_new_tokens=2))  # compile
+    ready = []
+
+    class LateLogits:
+        def __init__(self, logits):
+            self.logits = logits
+
+        def __jax_array__(self):
+            time.sleep(0.5)
+            ready.append(time.perf_counter())
+            return self.logits
+
+    orig_prefill = engine._prefill
+
+    def late_prefill(*args):
+        logits, cache = orig_prefill(*args)
+        return LateLogits(logits), cache
+
+    engine._prefill = late_prefill
+    reqs = engine.submit_many(prompts, max_new_tokens=3)
+    reqs[1].deadline_s = 0.3  # spent while the first tokens come back
+    done = {r.uid: r for r in engine.run(reqs)}
+    [t_ready] = ready
+    served, late = done[reqs[0].uid], done[reqs[1].uid]
+    assert served.status == "completed" and len(served.output) == 3
+    assert served.first_token_at >= t_ready
+    assert late.status == "timed_out"
+    assert late.output == [] and late.first_token_at == 0.0
+    assert late.done_at >= t_ready
