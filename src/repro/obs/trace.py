@@ -20,7 +20,8 @@ Span taxonomy (the names the stack emits; see README "Observability"):
                          token sync, then bookkeeping (its self time)
     serving/launch       the dispatch of a prefill or decode program
                          (child of serving/prefill or serving/decode)
-    serving/token_sync   argmax and the per-row device->host token reads
+    serving/token_sync   the greedy-token program and one device->host
+                         transfer of the step's tokens
                          (child of serving/prefill or serving/decode)
     serving/retire       end-of-batch request bookkeeping
     train/batch          host-side batch materialization

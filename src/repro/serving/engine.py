@@ -37,6 +37,12 @@ from repro.obs.trace import span
 from repro.serving import backend as backend_lib
 
 
+def _greedy_tokens(logits):
+    """Greedy tokens of one step: each row's argmax as a (B, 1) int32
+    array, the next decode step's input."""
+    return jnp.argmax(logits, axis=-1)[:, None].astype(jnp.int32)
+
+
 @dataclasses.dataclass
 class Request:
     uid: int
@@ -105,6 +111,7 @@ class ServingEngine:
         self._prefill = jax.jit(self._prefill_impl)
         self._decode = jax.jit(self._decode_impl)
         self._decode_verify = jax.jit(self._decode_verify_impl)
+        self._greedy = jax.jit(_greedy_tokens)
 
     # namespaces a compiled engine program may have routed through the
     # fallback ladder — what the runtime-failure path quarantines wholesale
@@ -285,11 +292,11 @@ class ServingEngine:
         tune_update: bool = False,
         tune_strategy: str = "predict",
     ) -> Optional[Dict[str, Any]]:
-        """Compile the prefill/decode programs for one prompt length before
-        traffic arrives; with ``tune=True`` first run the knob tuner for
-        this model's projection GEMM shapes — the fused GLU variant
-        included — so the SFC backend traces with tuned winners (a second
-        warmup for the same shape bucket is a pure cache hit — no
+        """Compile the prefill, decode and greedy-token programs for one
+        prompt length before traffic arrives; with ``tune=True`` first run
+        the knob tuner for this model's projection GEMM shapes — the fused
+        GLU variant included — so the SFC backend traces with tuned winners
+        (a second warmup for the same shape bucket is a pure cache hit — no
         re-measurement).
 
         Tuning is predict-then-confirm by default (tuner v2): the device is
@@ -347,8 +354,8 @@ class ServingEngine:
             }
         tokens = jnp.zeros((self.max_batch, prompt_len), jnp.int32)
         logits, cache = self._prefill(self.params, tokens)
-        tok = jnp.argmax(logits, axis=-1)[:, None].astype(jnp.int32)
-        jax.block_until_ready(self._decode(self.params, tok, cache))
+        logits, _ = self._decode(self.params, self._greedy(logits), cache)
+        jax.block_until_ready(self._greedy(logits))
         return stats
 
     # ---------------- jitted cores ----------------
@@ -507,15 +514,17 @@ class ServingEngine:
                 results.extend(batch)
         return results
 
-    @staticmethod
-    def _sync_tokens(logits, rows, phase: str):
-        """Greedy tokens of one step, brought to the host: the argmax on
-        the device, then one device→host read per row in ``rows``.
+    def _sync_tokens(self, logits, rows, phase: str):
+        """Greedy tokens of one step, brought to the host: the argmax in
+        one compiled program, then one device→host transfer of the whole
+        (B, 1) array; the rows in ``rows`` are picked on the host.
         Returns the device tokens (the next step's input), the rows'
         tokens as ints, and the time they reached the host."""
         with span("serving/token_sync"):
-            next_tok = jnp.argmax(logits, axis=-1)[:, None].astype(jnp.int32)
-            toks = [int(next_tok[i, 0]) for i in rows]
+            next_tok = self._greedy(logits)
+            host = np.asarray(next_tok)
+            obs_metrics.inc("serving.host_transfers", phase=phase)
+            toks = [int(host[i, 0]) for i in rows]
             obs_metrics.inc("serving.host_reads", value=len(toks), phase=phase)
             return next_tok, toks, time.perf_counter()
 

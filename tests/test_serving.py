@@ -317,6 +317,9 @@ def test_engine_spans_counters_and_token_times(small_model, monkeypatch,
     assert spans("prefill") == prefills
     assert spans("decode") == steps
     assert spans("launch") == spans("token_sync") == prefills + steps
+    transfers = reg.counter("serving.host_transfers")
+    assert transfers.value(phase="prefill") == prefills
+    assert transfers.value(phase="decode") == steps
     reads = reg.counter("serving.host_reads")
     assert reads.value(phase="prefill") == 5
     # the sum of live rows over the steps: each request's tokens after
@@ -325,6 +328,72 @@ def test_engine_spans_counters_and_token_times(small_model, monkeypatch,
         r.max_new_tokens - 1 for r in reqs) == 3 + 2 + 2 + 1 + 2 + 2
     itl = reg.histogram("serving.itl_us")
     assert itl.count() == sum(len(r.output) - 1 for r in done)
+
+
+@pytest.mark.parametrize("verify_every", [None, 2])
+def test_one_host_transfer_per_step_tokens_match_reference_loop(
+        small_model, monkeypatch, verify_every):
+    """The engine brings each step's greedy tokens to the host in one
+    transfer, and serves the same tokens as a plain loop over the model's
+    prefill and decode_step with a per-row argmax, while rows retire at
+    different steps (live rows 3, 2, 2, 1); ``verify_every=2`` sends
+    steps 2 and 4 through the verified decode."""
+    from repro import obs
+
+    monkeypatch.setenv("REPRO_OBS", "1")
+    cfg, model, params = small_model
+    max_seq, max_new = 32, (4, 2, 5)
+    engine = ServingEngine(cfg, params, max_batch=3, max_seq=max_seq,
+                           gemm_backend="sfc_pallas",
+                           verify_every=verify_every)
+    rng = np.random.default_rng(12)
+    prompts = [rng.integers(0, cfg.vocab, size=8).astype(np.int32)
+               for _ in max_new]
+    reqs = engine.submit_many(prompts)
+    for r, n in zip(reqs, max_new):
+        r.max_new_tokens = n
+    done = engine.run(reqs)
+
+    with gemm_backend("sfc_pallas"):
+        logits, cache = model.prefill(
+            params, jnp.asarray(np.stack(prompts)), cache_len=max_seq)
+        want = [[] for _ in max_new]
+        for _ in range(max(max_new)):
+            toks = [int(jnp.argmax(logits[i])) for i in range(len(max_new))]
+            for w, t in zip(want, toks):
+                w.append(t)
+            tok = jnp.asarray(toks, jnp.int32)[:, None]
+            logits, cache = model.decode_step(params, tok, cache)
+    assert [r.output for r in done] == [w[:n] for w, n in zip(want, max_new)]
+
+    reg = obs.registry()
+    steps = reg.counter("serving.decode_steps").total()
+    transfers = reg.counter("serving.host_transfers")
+    reads = reg.counter("serving.host_reads")
+    assert steps == 4
+    assert transfers.value(phase="decode") == steps
+    assert transfers.value(phase="prefill") == 1
+    assert reads.value(phase="decode") == 3 + 2 + 2 + 1
+    assert reads.value(phase="prefill") == 3
+
+
+def test_warmup_compiles_every_program_a_step_runs(small_model, monkeypatch):
+    """After ``warmup`` a full batch of the warmed prompt length lowers
+    nothing: the greedy-token program and the token transfer included."""
+    from repro import obs
+
+    monkeypatch.setenv("REPRO_OBS", "1")
+    cfg, model, params = small_model
+    engine = ServingEngine(cfg, params, max_batch=2, max_seq=32)
+    engine.warmup(prompt_len=8)
+    lowerings = obs.registry().counter("jax.lowerings")
+    before = lowerings.total()
+    rng = np.random.default_rng(13)
+    prompts = [rng.integers(0, cfg.vocab, size=8).astype(np.int32)
+               for _ in range(2)]
+    done = engine.run(engine.submit_many(prompts, max_new_tokens=3))
+    assert [len(r.output) for r in done] == [3, 3]
+    assert lowerings.total() == before
 
 
 def test_first_token_time_is_when_tokens_reach_the_host(small_model):
@@ -357,6 +426,10 @@ def test_first_token_time_is_when_tokens_reach_the_host(small_model):
         return LateLogits(logits), cache
 
     engine._prefill = late_prefill
+    # the greedy program takes arrays: the stand-in becomes one there,
+    # inside the token sync
+    orig_greedy = engine._greedy
+    engine._greedy = lambda logits: orig_greedy(jnp.asarray(logits))
     reqs = engine.submit_many(prompts, max_new_tokens=3)
     reqs[1].deadline_s = 0.3  # spent while the first tokens come back
     done = {r.uid: r for r in engine.run(reqs)}
