@@ -26,13 +26,23 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from chipbench import compare, flops, load_module, weights
+from chipbench import compare, load_module, weights
 from chipbench.trace import WINDOW_SPAN
 
 REFERENCES = pathlib.Path(__file__).resolve().parents[1] / "references"
 
 
 def load_reference(name: str):
+    """``references/<name>.py``: everything of a configuration that depends on
+    its architecture.  It provides
+
+    - ``params(model, seed)``: the benchmark's own weights from the seed,
+      laid out as the program's ``init`` lays them out;
+    - ``logits(params, model, tokens, positions, *, precision="f32")``: the
+      plain reference's logits, and with ``precision="fp8"`` the control's;
+    - ``round_work(batch, prompt_len, max_new, model, peaks)``: the numbers
+      one closed-loop round's work comes to, at least ``flops`` and
+      ``gemm_least_s``, and any others its metric readers take."""
     return load_module(REFERENCES / f"{name}.py", f"reference_{name}")
 
 
@@ -112,7 +122,7 @@ class Cell:
         self.setup_parts["warmup_s"] = time.perf_counter() - t0
 
     def make_params(self, seed: int):
-        params = weights.decoder_params(self.model, seed)
+        params = self.reference.params(self.model, seed)
         jax.block_until_ready(params)
         return params
 
@@ -192,13 +202,13 @@ class Cell:
         return out
 
     def work(self, win: Dict, peaks: Dict[str, float]) -> Dict[str, float]:
-        """Model FLOPs and least GEMM time of the window's rounds."""
+        """The reference's work counts of one round, times the window's
+        rounds."""
         t = self.traffic
-        one = flops.round_work(t["batch"], t["prompt_len"], t["max_new_tokens"],
-                               self.model, peaks)
+        one = self.reference.round_work(t["batch"], t["prompt_len"],
+                                        t["max_new_tokens"], self.model, peaks)
         n = len(win["rounds"])
-        return {"rounds": n, "flops": one["flops"] * n,
-                "gemm_least_s": one["gemm_least_s"] * n}
+        return {"rounds": n, **{k: v * n for k, v in one.items()}}
 
     def drop_engine(self) -> None:
         """Free the engine's state before the reference runs."""

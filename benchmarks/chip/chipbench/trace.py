@@ -4,13 +4,16 @@ A traced window is written by `jax.profiler` as an ``.xplane.pb``.  It is
 read with `jax.profiler.ProfileData` and cut down to a plain dict, the
 *events*::
 
-    {"devices": {"/device:TPU:0": {"ops": [[name, start_ns, dur_ns], ...],
-                                   "modules": [[name, start_ns, dur_ns], ...]}},
+    {"devices": {"/device:TPU:0": {
+         "ops": [[name, start_ns, dur_ns, name_stack], ...],
+         "modules": [[name, start_ns, dur_ns], ...]}},
      "host": [[name, start_ns, dur_ns], ...],
      "window": [start_ns, end_ns]}
 
 ``ops`` are the device's HLO operations (kernels, fusions, collectives),
-``modules`` the executions of whole compiled programs, ``host`` the
+each with its JAX name stack (``jit(f)/outer/while/body/inner/dot_general:``,
+"" where the op has none; events recorded before it was kept have three
+fields), ``modules`` the executions of whole compiled programs, ``host`` the
 annotations and calls of the Python thread that drives the chip, and
 ``window`` the span of the benchmark's ``bench/window`` annotation.  Every
 metric below is computed from these events alone, so a recorded trace
@@ -19,8 +22,10 @@ metric below is computed from these events alone, so a recorded trace
 
 from __future__ import annotations
 
+import functools
 import glob
 import os
+import pathlib
 import re
 from typing import Dict, Iterable, List, NamedTuple, Sequence, Tuple
 
@@ -28,6 +33,8 @@ WINDOW_SPAN = "bench/window"
 # device lines, by the names the TPU profiler gives them
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
+# the stat of an op's event metadata that holds its JAX name stack (TPU v5e)
+NAME_STACK_STAT = "tf_op"
 # control flow: an op of these spans the ops of its body on the same line
 CONTROL = ("while", "conditional", "call")
 # collectives as XLA names their HLO operations
@@ -55,6 +62,7 @@ def extract(path: str) -> dict:
     from jax.profiler import ProfileData
 
     pd = ProfileData.from_file(path)
+    stacks = name_stacks(path)
     devices: Dict[str, dict] = {}
     host: List[list] = []
     planes: Dict[str, List[str]] = {}
@@ -65,7 +73,8 @@ def extract(path: str) -> dict:
             if OPS_LINE not in lines:
                 continue
             devices[plane.name] = {
-                "ops": _events(lines[OPS_LINE].events),
+                "ops": _with_stacks(_events(lines[OPS_LINE].events),
+                                    stacks.get(plane.name, [])),
                 "modules": (_events(lines[MODULES_LINE].events)
                             if MODULES_LINE in lines else []),
             }
@@ -82,6 +91,91 @@ def extract(path: str) -> dict:
 
 def _events(events: Iterable) -> List[list]:
     return [[e.name, float(e.start_ns), float(e.duration_ns)] for e in events]
+
+
+def _with_stacks(ops: List[list], stacks: List[Tuple[str, str]]) -> List[list]:
+    """``ops`` with each event's name stack appended.  ``stacks`` holds the
+    (name, name stack) of the same events in the same order; a name that
+    differs means the two readings are out of step, which is an error."""
+    if len(stacks) != len(ops) or any(
+            e[0] != name for e, (name, _) in zip(ops, stacks)):
+        raise ValueError("the ops line's events and their metadata differ")
+    return [e + [stack] for e, (_, stack) in zip(ops, stacks)]
+
+
+# --- the protobuf itself: `ProfileData` does not expose event metadata ---
+
+
+@functools.lru_cache(maxsize=None)
+def _xspace_class():
+    """A message class for the part of tsl's ``xplane.proto`` that the name
+    stacks need, field numbers as there; every other field is skipped.
+    Names are read as bytes, so that no text is checked as UTF-8."""
+    from google.protobuf import descriptor_pb2, descriptor_pool, message_factory
+
+    f = descriptor_pb2.FieldDescriptorProto
+    one, many = f.LABEL_OPTIONAL, f.LABEL_REPEATED
+    kinds = {"int": f.TYPE_INT64, "uint": f.TYPE_UINT64, "bytes": f.TYPE_BYTES}
+    proto = descriptor_pb2.FileDescriptorProto(
+        name="chipbench_xplane.proto", package="chipbench", syntax="proto3")
+    messages = {
+        "XSpace": [("planes", 1, many, "XPlane")],
+        "XPlane": [("name", 2, one, "bytes"), ("lines", 3, many, "XLine"),
+                   ("event_metadata", 4, many, "EventMetadataEntry"),
+                   ("stat_metadata", 5, many, "StatMetadataEntry")],
+        "XLine": [("name", 2, one, "bytes"), ("events", 4, many, "XEvent")],
+        "XEvent": [("metadata_id", 1, one, "int")],
+        # a map<int64, X> is, on the wire, a repeated entry of key 1, value 2
+        "EventMetadataEntry": [("key", 1, one, "int"),
+                               ("value", 2, one, "XEventMetadata")],
+        "XEventMetadata": [("name", 2, one, "bytes"),
+                           ("stats", 5, many, "XStat")],
+        "StatMetadataEntry": [("key", 1, one, "int"),
+                              ("value", 2, one, "XStatMetadata")],
+        "XStatMetadata": [("name", 2, one, "bytes")],
+        "XStat": [("metadata_id", 1, one, "uint"), ("str_value", 5, one, "bytes"),
+                  ("ref_value", 7, one, "uint")],
+    }
+    for name, fields in messages.items():
+        m = proto.message_type.add(name=name)
+        for fname, number, label, kind in fields:
+            field = m.field.add(name=fname, number=number, label=label)
+            if kind in kinds:
+                field.type = kinds[kind]
+            else:
+                field.type, field.type_name = f.TYPE_MESSAGE, f".chipbench.{kind}"
+    pool = descriptor_pool.DescriptorPool()
+    pool.Add(proto)
+    return message_factory.GetMessageClass(
+        pool.FindMessageTypeByName("chipbench.XSpace"))
+
+
+def name_stacks(path: str) -> Dict[str, List[Tuple[str, str]]]:
+    """For each device plane of an ``.xplane.pb``, the (name, name stack) of
+    every event of its ops line, in order.  The name stack is the
+    ``NAME_STACK_STAT`` of the event's metadata (its text, or the name of the
+    stat metadata it refers to), "" where it has none."""
+    space = _xspace_class().FromString(pathlib.Path(path).read_bytes())
+    out: Dict[str, List[Tuple[str, str]]] = {}
+    for plane in space.planes:
+        name = plane.name.decode()
+        lines = [line for line in plane.lines if line.name == OPS_LINE.encode()]
+        if not name.startswith("/device:") or not lines:
+            continue
+        stat_names = {e.key: e.value.name.decode("utf-8", "replace")
+                      for e in plane.stat_metadata}
+        metadata: Dict[int, Tuple[str, str]] = {}
+        for e in plane.event_metadata:
+            stack = ""
+            for stat in e.value.stats:
+                if stat_names.get(stat.metadata_id) == NAME_STACK_STAT:
+                    stack = (stat.str_value.decode("utf-8", "replace")
+                             if stat.str_value
+                             else stat_names.get(stat.ref_value, ""))
+            metadata[e.key] = (e.value.name.decode("utf-8", "replace"), stack)
+        get = metadata.get
+        out[name] = [get(e.metadata_id, ("", "")) for e in lines[0].events]
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -156,6 +250,7 @@ class Op(NamedTuple):
     end: float
     self_ns: float  # a control-flow op's duration less its body's ops
     leaf: bool      # not a control-flow op
+    stack: str      # its JAX name stack, "" where the events hold none
 
 
 class Trace:
@@ -173,21 +268,22 @@ class Trace:
             raise ValueError("the trace holds no device with an ops line")
         win = events.get("window")
         if win is None:  # no host annotation: the span of the device ops
-            spans = [(s, s + d) for dev in self.devices
-                     for _, s, d in events["devices"][dev]["ops"]]
+            spans = [(e[1], e[1] + e[2]) for dev in self.devices
+                     for e in events["devices"][dev]["ops"]]
             win = [min(s for s, _ in spans), max(e for _, e in spans)]
         self.lo, self.hi = float(win[0]), float(win[1])
         self._ops = {d: self._nest(events["devices"][d]["ops"])
                      for d in self.devices}
 
     def _nest(self, raw) -> List[Op]:
-        ops = [(t, base_name(t), max(s, self.lo), min(s + d, self.hi))
-               for t, s, d in raw if s + d > self.lo and s < self.hi]
-        inner = union((s, e) for _, b, s, e in ops if b not in CONTROL)
+        ops = [(t, base_name(t), max(s, self.lo), min(s + d, self.hi),
+                stack[0] if stack else "")
+               for t, s, d, *stack in raw if s + d > self.lo and s < self.hi]
+        inner = union((s, e) for _, b, s, e, _ in ops if b not in CONTROL)
         return [Op(t, b, s, e, (e - s) - (total(clip(inner, s, e))
                                           if b in CONTROL else 0.0),
-                   b not in CONTROL)
-                for t, b, s, e in ops]
+                   b not in CONTROL, stack)
+                for t, b, s, e, stack in ops]
 
     @property
     def window_s(self) -> float:
@@ -220,6 +316,18 @@ class Trace:
                 feeds = {n for o in kernels for n in operand_names(o.text)}
                 out += sum(o.end - o.start for o in self._ops[d]
                            if staging(o.base) and op_name(o.text) in feeds)
+        return out * 1e-9 / len(self.devices)
+
+    def scope_seconds(self, scope: str) -> float:
+        """Device time of the leaf ops whose name stack holds the
+        ``jax.named_scope(scope)`` (whole segments of the stack: ``"moe"``
+        or ``"moe/route"``), averaged over the devices.  0 where no op's
+        does, and where the events hold no name stacks."""
+        mark = f"/{scope}/"
+        out = 0.0
+        for d in self.devices:
+            out += total(union((o.start, o.end) for o in self._ops[d]
+                               if o.leaf and mark in f"/{o.stack.rstrip(':')}/"))
         return out * 1e-9 / len(self.devices)
 
     def exposed_seconds(self, match) -> float:
@@ -304,7 +412,7 @@ def trimmed(events: dict, max_ops: int = 400) -> dict:
         ops = sorted(dev["ops"], key=lambda e: e[1])[:max_ops]
         devs[name] = {"ops": ops, "modules": [
             m for m in dev["modules"] if ops and m[1] <= ops[-1][1]]}
-        end = max(s + d for t, s, d in ops if base_name(t) not in CONTROL)
+        end = max(e[1] + e[2] for e in ops if base_name(e[0]) not in CONTROL)
         hi = end if hi is None else max(hi, end)
     lo = events["window"][0] if events.get("window") else None
     host = [h for h in events.get("host", []) if hi is None or h[1] <= hi]

@@ -1,10 +1,11 @@
 """Weights and operands made on the device from ``--seed``.
 
 The benchmark makes the weights itself, so that its reference takes nothing
-the program made.  They are laid out as the program's decoder expects them
-(`run.py` checks the tree against the program's own ``init``) and made in
-one jitted call, layer by layer inside it (`lax.map`), in the dtype they are
-served in.
+the program made.  A configuration's reference module names the function
+that makes them (``params``); they are laid out as the program's decoder
+expects them (`serve.Cell` checks the tree against the program's own
+``init``) and made in one jitted call, layer by layer inside it
+(`lax.map`), in the dtype they are served in.
 """
 
 from __future__ import annotations

@@ -17,6 +17,9 @@ program.  ``precision="fp8"`` is the control: every projection and head
 matmul takes its operands rounded to float8 e4m3 (scaled per row of the
 activations and per column of the weights, accumulation in float32), the
 step a faster program would be tempted to take from bf16.
+
+The configuration's weights (``params``) and work counts (``round_work``)
+are the harness's dense ones.
 """
 
 from __future__ import annotations
@@ -26,6 +29,11 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from chipbench import flops, weights
+
+params = weights.decoder_params
+round_work = flops.round_work
 
 HI = jax.lax.Precision.HIGHEST
 RMS_EPS_DEFAULT = 1e-6

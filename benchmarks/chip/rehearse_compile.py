@@ -48,14 +48,14 @@ def serve_programs(spec, topo):
     from repro.configs.base import ArchConfig
     from repro.serving.engine import ServingEngine
 
-    from chipbench import serve, weights
+    from chipbench import serve
 
     model, t = spec["config"]["model"], spec["traffic"]
     one = SingleDeviceSharding(topo.devices[0])
     fields = {f.name for f in dataclasses.fields(ArchConfig)}
     cfg = ArchConfig(**{k: v for k, v in model.items() if k in fields})
-    params = _shapes(jax.eval_shape(
-        lambda: weights.decoder_params(model, 0)), one)
+    ref = serve.load_reference(spec["config"]["reference"])
+    params = _shapes(jax.eval_shape(lambda: ref.params(model, 0)), one)
     engine = ServingEngine(cfg, None, max_batch=t["max_batch"],
                            max_seq=t["max_seq"], gemm_backend="sfc_pallas")
     tokens = jax.ShapeDtypeStruct((t["batch"], t["prompt_len"]), jnp.int32,
@@ -68,7 +68,6 @@ def serve_programs(spec, topo):
         token = jax.ShapeDtypeStruct((t["batch"], 1), jnp.int32, sharding=one)
         _report("decode", jax.jit(engine._decode_impl).lower(
             params, token, cache).compile())
-    ref = serve.load_reference(spec["config"]["reference"])
     frozen = tuple(sorted((k, v) for k, v in model.items()
                           if isinstance(v, (int, float, str, bool))))
     seq = t["prompt_len"] + t["max_new_tokens"] - 1

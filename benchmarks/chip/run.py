@@ -5,10 +5,12 @@
 
 Everything a cell is made of is found by name: the cell in
 ``BENCHMARK.json`` names its configuration (``configs/<config>.json``, which
-names its plain reference, ``references/<reference>.py``) and its traffic
+names its reference module, ``references/<reference>.py``: the weights, the
+plain reference and the work counts of its architecture) and its traffic
 (``traffic/<traffic>.json``); ``limits/<cell>.json`` holds the limits of
 its correctness check and ``metrics/<metric>.py`` reads each per-layer
-metric.  Adding a cell adds files and entries and edits none.
+metric.  Adding a cell, or a decoder of a new architecture, adds files and
+entries and edits none.
 
 ``--trace 0`` measures the cell's end-to-end metrics over ``--seconds``;
 ``--trace 1`` traces the traffic's ``trace_rounds`` rounds with the JAX
@@ -154,8 +156,10 @@ def traced_window(cell, seed: int, rounds: int, out_dir: str,
         win = cell.window(seed, 0.0, n_rounds=rounds, annotate=TraceAnnotation)
     finally:
         jax.profiler.stop_trace()
+    t0 = time.perf_counter()
     events = trace.extract(trace.xplane_path(out_dir))
     reduced = trace.Trace(events)
+    log(f"trace read in {time.perf_counter() - t0!r} s")
     if save_events:
         pathlib.Path(save_events).write_text(json.dumps(
             {**trace.trimmed(events), "summary": reduced.summary()}))
